@@ -674,7 +674,6 @@ def bench_sharded_decode(batch: int = 4, heads: int = 8, kv_heads: int = 2,
     from jax.sharding import PartitionSpec as P
 
     from repro.core.backends import PallasSplitKAttention
-    from repro.distributed.sharding import shard_map_compat
     from repro.launch.mesh import make_mesh
     from repro.models.attention import sharded_decode_attend
 
@@ -702,9 +701,9 @@ def bench_sharded_decode(batch: int = 4, heads: int = 8, kv_heads: int = 2,
             return o
 
         kv_spec = P(None, None, "seq", None)
-        f = jax.jit(shard_map_compat(
+        f = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(P(), kv_spec, kv_spec, P()),
-            out_specs=P()))
+            out_specs=P(), check_vma=False))
         np.asarray(f(q, k, v, pos))  # warmup: trace + compile
         t0 = time.perf_counter()
         for _ in range(repeats):

@@ -45,6 +45,10 @@ def main(argv=None) -> None:
                     help="also write all rows to PATH (default BENCH_fsi.json)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
+
     from benchmarks import (
         bench_cost_model,
         bench_fsi_channels,

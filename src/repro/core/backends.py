@@ -22,7 +22,7 @@ the numbers.  This module makes that choice pluggable:
 * ``pallas-bsr-sharded`` — the same fleet panel laid out over a real device
   mesh: the stacked worker axis is sharded over a 1-D ``worker`` mesh axis
   (``launch.mesh.make_worker_mesh``) and each layer dispatches through
-  ``distributed.sharding.shard_map_compat``, so simulated workers map 1:1
+  ``jax.shard_map``, so simulated workers map 1:1
   (or blocked P/D) onto devices — the paper's "one worker ≈ one isolated
   compute unit" execution model.  The default ``dispatch="fused"`` runs ONE
   fleet-megakernel ``pallas_call`` per device (worker index folded into the
@@ -186,9 +186,8 @@ class _PallasFleetState:
 class PallasBsrBackend:
     """MXU-tiled BSR SpMM via ``kernels/bsr_spmm`` (fused bias+ReLU+clip).
 
-    ``interpret=True`` (the default) runs the Pallas kernel through the
-    interpreter, which works on CPU-only hosts; on a real TPU pass
-    ``interpret=False`` for compiled MXU dispatch.
+    ``interpret=None`` (the default) resolves from the platform: compiled
+    MXU dispatch on TPU, the Pallas interpreter on every other host.
     """
 
     name = "pallas-bsr"
@@ -197,15 +196,14 @@ class PallasBsrBackend:
         self,
         block_shape: Tuple[int, int] = (32, 32),
         batch_block: int = 128,
-        interpret: bool = True,
+        interpret: Optional[bool] = None,
         clip: float = ACTIVATION_CLIP,
     ):
-        import jax  # gate the optional accelerator dep at construction time
+        from repro.kernels import resolve_interpret
 
-        del jax
         self.block_shape = block_shape
         self.batch_block = batch_block
-        self.interpret = interpret
+        self.interpret = resolve_interpret(interpret)
         self.clip = clip
 
     @property
@@ -387,7 +385,7 @@ class PallasBsrShardedBackend(PallasBsrBackend):
         self,
         block_shape: Tuple[int, int] = (32, 32),
         batch_block: int = 128,
-        interpret: bool = True,
+        interpret: Optional[bool] = None,
         clip: float = ACTIVATION_CLIP,
         mesh: Any = None,
         axis_name: str = "worker",
@@ -744,14 +742,26 @@ _ATTENTION_REGISTRY: Dict[str, type] = {
 }
 ATTENTION_BACKEND_NAMES = tuple(_ATTENTION_REGISTRY)
 
-# kind → (registry, default name, label, duck-type method an instance of the
-# kind must expose — catches a wrong-kind instance at resolution time instead
-# of an AttributeError deep inside a jit trace)
+# kind → (registry, (default off-TPU, default on TPU), label, duck-type
+# method an instance of the kind must expose — catches a wrong-kind instance
+# at resolution time instead of an AttributeError deep inside a jit trace).
+# The defaults: the host path or oracle on CPU hosts, the device kernel on a
+# TPU.
 _KINDS = {
-    "compute": (_REGISTRY, "numpy-fast", "compute backend", "apply"),
-    "attention": (_ATTENTION_REGISTRY, "dense-ref", "attention backend",
-                  "decode"),
+    "compute": (_REGISTRY, ("numpy-fast", "pallas-bsr-sharded"),
+                "compute backend", "apply"),
+    "attention": (_ATTENTION_REGISTRY, ("dense-ref", "pallas-splitk"),
+                  "attention backend", "decode"),
 }
+
+
+def _on_tpu() -> bool:
+    try:
+        import jax
+    except ImportError:
+        return False
+    return jax.default_backend() == "tpu"
+
 
 _LEGACY = object()  # sentinel: one-argument get_backend(name) = compute
 
@@ -761,10 +771,12 @@ def get_backend(kind, name=_LEGACY):
     both registries.
 
     ``get_backend("compute", "numpy-fast")`` / ``get_backend("attention",
-    "pallas-splitk")``.  ``name=None`` resolves to the kind's default
-    (``numpy-fast`` — the default since PR 1 — and ``dense-ref``, the
-    oracle).  Instances pass through unchanged, so callers can hand in a
-    pre-configured backend (e.g. ``ChunkedLseAttention(kv_chunk=256)``).
+    "pallas-splitk")``.  ``name=None`` resolves from ``jax.default_backend()``:
+    the fleet megakernel (``pallas-bsr-sharded``) and the split-K decode
+    kernel (``pallas-splitk``) on TPU, ``numpy-fast`` and the ``dense-ref``
+    oracle on every other host.  Instances pass through unchanged, so
+    callers can hand in a pre-configured backend (e.g.
+    ``ChunkedLseAttention(kv_chunk=256)``).
 
     The legacy one-argument form ``get_backend(name_or_instance)`` still
     means a compute backend (every PR 1 call site).
@@ -775,9 +787,9 @@ def get_backend(kind, name=_LEGACY):
         raise ValueError(
             f"unknown backend kind {kind!r}; options: {tuple(_KINDS)}"
         )
-    registry, default, label, duck_method = _KINDS[kind]
+    registry, defaults, label, duck_method = _KINDS[kind]
     if name is None:
-        name = default
+        name = defaults[_on_tpu()]
     if not isinstance(name, str):
         if not callable(getattr(name, duck_method, None)):
             raise TypeError(
@@ -792,5 +804,5 @@ def get_backend(kind, name=_LEGACY):
         ) from None
     except ImportError as e:  # pallas-* without jax installed
         raise ImportError(
-            f"backend {name!r} needs jax; install it or use {default!r}"
+            f"backend {name!r} needs jax; install it or use {defaults[0]!r}"
         ) from e

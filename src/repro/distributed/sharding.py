@@ -32,22 +32,6 @@ from repro.launch.mesh import MeshAxes
 PyTree = Any
 
 
-def shard_map_compat(body, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: new API (``check_vma``),
-    pre-0.6 top-level API (``check_rep``), or the experimental module."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 # ---------------------------------------------------------------------------
 # parameter specs
 # ---------------------------------------------------------------------------

@@ -313,6 +313,10 @@ def run_lm_pipeline(
     import jax
     import jax.numpy as jnp
 
+    from repro.serving.router import route_attention_backend
+
+    if attn_backend is None:
+        attn_backend = route_attention_backend(cfg)
     latency = latency or LatencyModel()
     compute = compute or ComputeModel()
     prompts = np.asarray(prompts)

@@ -1,3 +1,15 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the serving hot paths (see README.md)."""
+
+from __future__ import annotations
+
+from typing import Optional
+
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """``None`` → the platform default: the Pallas interpreter off-TPU,
+    compiled dispatch on a TPU.  An explicit flag passes through."""
+    if interpret is None:
+        import jax
+
+        return jax.default_backend() != "tpu"
+    return bool(interpret)

@@ -13,99 +13,97 @@ Layout (padded BSR, built offline):
   x       f32[N, B]             dense activations (batch panel)
   y       f32[M, B]             y = clip(relu(Wx + bias), 0, clip)
 
-Grid: (row-blocks, batch-panels).  The K nonzero blocks of one row-block are
-staged into VMEM via the BlockSpec; x panels are sliced dynamically by block
-column id (pl.ds) from the full-x VMEM block — N·bb·4B must fit VMEM, which
-holds for every GraphChallenge size at bb = 128.
+Grid: (batch-panels, row-blocks).  The block-column ids (and the fleet's
+per-row block counts) are scalar-prefetched into SMEM, where they feed the
+``pl.ds`` starts and ``fori_loop`` bounds; the K nonzero blocks of one
+row-block stream into VMEM via the BlockSpec, and x panels are sliced by
+block column id from the full-x VMEM block, which stays resident across the
+inner row-block sweep.  N·bb·4B must fit VMEM.
+
+The block products run at ``Precision.HIGHEST``: f32 on the MXU otherwise
+rounds x to bf16, and GraphChallenge activations are not bf16-exact.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _block_dot(wb, xb):
+    return jnp.dot(wb, xb, precision=_HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
+def _vmem_limit(n: int, bb: int, k_max: int, bm: int, bn: int) -> int:
+    """Scoped-VMEM budget for one grid step, double-buffered: the resident
+    x panel [n, bb], one row-block's K weight blocks (minor dim padded to
+    128 lanes) and the output tile, plus 4 MiB of headroom.  Never below
+    the chip's 16 MiB default; a shape past the 128 MiB of v5e VMEM is left
+    to the compiler to refuse."""
+    lanes = lambda d: -(-d // 128) * 128
+    need = 2 * 4 * (n * lanes(bb) + k_max * bm * lanes(bn) + bm * lanes(bb))
+    return max(16 << 20, need + (4 << 20))
 
 
 def _kernel(cols_ref, blocks_ref, x_ref, y_ref, *, bn: int, k_max: int,
             bias: float, clip: float):
+    r = pl.program_id(1)
     bm = blocks_ref.shape[2]
     bb = y_ref.shape[1]
-    acc0 = jnp.zeros((bm, bb), jnp.float32)
 
     def body(i, acc):
-        c = cols_ref[0, i]
-        xb = x_ref[pl.ds(c * bn, bn), :]
-        wb = blocks_ref[0, i]
-        return acc + jnp.dot(wb, xb, preferred_element_type=jnp.float32)
+        c = pl.multiple_of(cols_ref[r, i] * bn, bn)
+        return acc + _block_dot(blocks_ref[0, i], x_ref[pl.ds(c, bn), :])
 
-    acc = jax.lax.fori_loop(0, k_max, body, acc0)
+    acc = jax.lax.fori_loop(0, k_max, body, jnp.zeros((bm, bb), jnp.float32))
     y_ref[...] = jnp.clip(acc + bias, 0.0, clip)
 
 
-def _fleet_kernel(counts_ref, cols_ref, blocks_ref, x_ref, y_ref, *, bn: int,
-                  k_max: int, bias: float, clip: float, count_bounded: bool):
-    """One (worker panel, batch-panel) cell of the fleet megakernel.
+def _fleet_kernel(counts_ref, cols_ref, blocks_ref, x_ref, y_ref, *, nbr: int,
+                  k_max: int, bn: int, bias: float, clip: float):
+    """One (worker, batch-panel, row-block) cell of the fleet megakernel.
 
-    The cell computes its worker's ENTIRE layer panel — all NBR row blocks.
-    Two lowerings of the same math:
-
-    * ``count_bounded`` (compiled TPU dispatch): nested ``fori_loop`` over
-      row blocks and each row's REAL block count (``counts_ref``, the BSR
-      indptr diff) with ``pl.ds`` x-panel slices — the scalar-core loops
-      skip the fleet-global K padding entirely.
-    * interpreter (CPU hosts): one fancy-index gather of the referenced x
-      block rows plus K batched [NBR, bm, bn] × [NBR, bn, bb] matmuls
-      accumulated in ascending-k order — a tiny constant-size trace that
-      executes as vectorized host ops instead of thousands of per-cell
-      interpreter steps, with the SAME per-block contraction and k-sum
-      order as the sequential lowerings (bitwise-parity asserted against
-      the vmap dispatch in ``tests/test_sharded_fleet.py``).
-
-    Padding blocks are all-zero, so the count bound only drops exact +0.0
-    terms — the two lowerings agree bitwise.
+    The K loop runs to the row's REAL block count (``counts_ref``, the BSR
+    indptr diff), so it skips the fleet-global K padding.  ``counts`` and
+    ``cols`` arrive flattened: SMEM pads a 2-D array's minor dim to 128
+    words, which overflows its 1 MiB at N=65536.  Padding blocks
+    are all-zero, so the bound only drops exact +0.0 terms and the result
+    matches :func:`_fleet_host_lowering` bitwise on CPU hosts (asserted in
+    ``tests/test_kernels.py``).
     """
-    nbr, _, bm = blocks_ref.shape[1:4]
+    row = pl.program_id(0) * nbr + pl.program_id(2)   # flat (worker, row)
+    bm = blocks_ref.shape[3]
     bb = y_ref.shape[2]
-    if count_bounded:
-        def row(r, _):
-            def body(i, acc):
-                c = cols_ref[0, r, i]
-                xb = x_ref[0, pl.ds(c * bn, bn), :]
-                wb = blocks_ref[0, r, i]
-                return acc + jnp.dot(wb, xb,
-                                     preferred_element_type=jnp.float32)
 
-            acc = jax.lax.fori_loop(0, counts_ref[0, r], body,
-                                    jnp.zeros((bm, bb), jnp.float32))
-            y_ref[0, pl.ds(r * bm, bm), :] = jnp.clip(acc + bias, 0.0, clip)
-            return 0
+    def body(i, acc):
+        c = pl.multiple_of(cols_ref[row * k_max + i] * bn, bn)
+        return acc + _block_dot(blocks_ref[0, 0, i], x_ref[0, pl.ds(c, bn), :])
 
-        jax.lax.fori_loop(0, nbr, row, 0)
-    else:
-        offs = jax.lax.broadcasted_iota(jnp.int32, (nbr, k_max, bn), 2)
-        idx = (cols_ref[0] * bn)[:, :, None] + offs        # [NBR, K, bn]
-        xg = x_ref[0][idx.reshape(nbr, k_max * bn), :]     # [NBR·K·bn, bb]
-        xg = xg.reshape(nbr, k_max, bn, bb)
-        acc = jnp.zeros((nbr, bm, bb), jnp.float32)
-        for i in range(k_max):  # ascending k, same accumulation order as
-            acc = acc + jax.lax.dot_general(   # the sequential lowerings
-                blocks_ref[0, :, i], xg[:, i],
-                (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)
-        y_ref[0] = jnp.clip(acc.reshape(nbr * bm, bb) + bias, 0.0, clip)
+    acc = jax.lax.fori_loop(0, counts_ref[row], body,
+                            jnp.zeros((bm, bb), jnp.float32))
+    y_ref[0] = jnp.clip(acc + bias, 0.0, clip)
 
 
 def _fleet_host_lowering(blocks, cols, x, bias: float, clip: float):
-    """The megakernel math for the whole device shard as straight XLA ops.
+    """The megakernel math for the whole device shard as straight XLA ops —
+    the CPU lowering.
 
-    Identical to ``_fleet_kernel``'s interpreter branch with the worker axis
-    vectorized in: one fancy gather of every referenced x block row plus K
-    batched matmuls accumulated in ascending-k order.  The Pallas
-    interpreter pays ~1ms of staging per grid cell on CPU hosts, so the
-    backends route ``interpret=True`` dispatch here; bitwise parity with
-    the interpreted Pallas grid is asserted in ``tests/test_kernels.py``.
+    One fancy gather of every referenced x block row plus K batched matmuls
+    accumulated in ascending-k order, the same per-block contraction and
+    k-sum order as the kernel.  The Pallas interpreter pays ~1ms of staging
+    per grid cell on CPU hosts, so interpreted dispatch routes here; bitwise
+    parity with the interpreted Pallas grid is asserted in
+    ``tests/test_kernels.py``.
     """
     p, nbr, k_max, bm, bn = blocks.shape
     b = x.shape[2]
@@ -118,7 +116,7 @@ def _fleet_host_lowering(blocks, cols, x, bias: float, clip: float):
         acc = acc + jax.lax.dot_general(
             blocks[:, :, i], xg[:, :, i],
             (((3,), (2,)), ((0, 1), (0, 1))),
-            preferred_element_type=jnp.float32)
+            precision=_HIGHEST, preferred_element_type=jnp.float32)
     return jnp.clip(acc.reshape(p, nbr * bm, b) + bias, 0.0, clip)
 
 
@@ -130,28 +128,28 @@ def bsr_spmm_fleet_megakernel(
     bias: float,
     clip: float = 32.0,
     batch_block: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     force_grid: bool = False,
 ) -> jnp.ndarray:
     """The whole worker fleet (or one device's shard of it) in ONE
-    ``pallas_call``: the grid iterates the device's blocked worker panels
-    (leading grid dimension = worker index) × batch panels, and each cell
-    streams its worker's full row-block set — so every panel flows through
-    the kernel without re-entering XLA (or a vmap batching rule) between
-    workers.
+    ``pallas_call``: the grid walks the device's worker panels (leading grid
+    dimension = worker index) × batch panels × row blocks, so every panel
+    flows through the kernel without re-entering XLA (or a vmap batching
+    rule) between workers.
 
     The per-worker BSR structure arrives device-local from
     ``fleet_prepare_all``: padded ``blocks``/``cols`` panels concatenated
     along the worker axis plus ``counts`` (the per-row true block count, the
-    BSR indptr diff) which bounds the compiled K loops — see
-    ``_fleet_kernel`` for the two lowerings.
+    BSR indptr diff) which bounds the K loops.
 
-    ``interpret=True`` (CPU hosts) routes through
+    ``interpret=None`` resolves from the platform: compiled on TPU, and on
+    other hosts the interpreted dispatch routes through
     :func:`_fleet_host_lowering` — the same math as vectorized XLA ops —
-    because the Pallas interpreter's per-grid-cell staging dominates at
-    fleet grid sizes; pass ``force_grid=True`` to run the interpreted
-    Pallas grid itself (the parity tests do).  Returns ``y [P, NBR*bm, B]``.
+    because the interpreter's per-grid-cell staging dominates at fleet grid
+    sizes.  ``force_grid=True`` runs the interpreted Pallas grid itself (the
+    parity tests do).  Returns ``y [P, NBR*bm, B]``.
     """
+    interpret = resolve_interpret(interpret)
     p, nbr, k_max, bm, bn = blocks.shape
     p2, n, b = x.shape
     assert p == p2, (p, p2)
@@ -159,22 +157,25 @@ def bsr_spmm_fleet_megakernel(
         return _fleet_host_lowering(blocks, cols, x, bias, clip)
     bb = min(batch_block, b)
     assert b % bb == 0, "batch_block (clamped to batch) must divide batch"
-    grid = (p, b // bb)
     return pl.pallas_call(
-        functools.partial(_fleet_kernel, bn=bn, k_max=k_max, bias=bias,
-                          clip=clip, count_bounded=not interpret),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, nbr), lambda w, j: (w, 0)),              # counts
-            pl.BlockSpec((1, nbr, k_max), lambda w, j: (w, 0, 0)),    # cols
-            pl.BlockSpec((1, nbr, k_max, bm, bn),
-                         lambda w, j: (w, 0, 0, 0, 0)),               # blocks
-            pl.BlockSpec((1, n, bb), lambda w, j: (w, 0, j)),         # x panel
-        ],
-        out_specs=pl.BlockSpec((1, nbr * bm, bb), lambda w, j: (w, 0, j)),
+        functools.partial(_fleet_kernel, nbr=nbr, k_max=k_max, bn=bn,
+                          bias=bias, clip=clip),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,                                   # counts, cols
+            grid=(p, b // bb, nbr),
+            in_specs=[
+                pl.BlockSpec((1, 1, k_max, bm, bn),
+                             lambda w, j, r, *_: (w, r, 0, 0, 0)),   # blocks
+                pl.BlockSpec((1, n, bb), lambda w, j, r, *_: (w, 0, j)),  # x
+            ],
+            out_specs=pl.BlockSpec((1, bm, bb),
+                                   lambda w, j, r, *_: (w, r, j)),
+        ),
         out_shape=jax.ShapeDtypeStruct((p, nbr * bm, b), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(n, bb, k_max, bm, bn)),
         interpret=interpret,
-    )(counts, cols, blocks, x)
+    )(counts.reshape(-1), cols.reshape(-1), blocks, x)
 
 
 def bsr_spmm_fused(
@@ -184,22 +185,27 @@ def bsr_spmm_fused(
     bias: float,
     clip: float = 32.0,
     batch_block: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
+    interpret = resolve_interpret(interpret)
     nbr, k_max, bm, bn = blocks.shape
     n, b = x.shape
     bb = min(batch_block, b)
     assert b % bb == 0, "batch_block (clamped to batch) must divide batch"
-    grid = (nbr, b // bb)
     return pl.pallas_call(
         functools.partial(_kernel, bn=bn, k_max=k_max, bias=bias, clip=clip),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, k_max), lambda i, j: (i, 0)),            # cols
-            pl.BlockSpec((1, k_max, bm, bn), lambda i, j: (i, 0, 0, 0)),  # blocks
-            pl.BlockSpec((n, bb), lambda i, j: (0, j)),               # x panel
-        ],
-        out_specs=pl.BlockSpec((bm, bb), lambda i, j: (i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                                   # cols
+            grid=(b // bb, nbr),
+            in_specs=[
+                pl.BlockSpec((1, k_max, bm, bn),
+                             lambda j, i, _: (i, 0, 0, 0)),          # blocks
+                pl.BlockSpec((n, bb), lambda j, i, _: (0, j)),       # x panel
+            ],
+            out_specs=pl.BlockSpec((bm, bb), lambda j, i, _: (i, j)),
+        ),
         out_shape=jax.ShapeDtypeStruct((nbr * bm, b), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(n, bb, k_max, bm, bn)),
         interpret=interpret,
     )(cols, blocks, x)

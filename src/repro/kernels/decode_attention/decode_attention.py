@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -38,7 +40,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     v = v_ref[0, 0].astype(jnp.float32)
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # [G, bk]
     kpos = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-    s = jnp.where(kpos < len_ref[0], s, NEG_INF)
+    s = jnp.where(kpos < len_ref[0, 0], s, NEG_INF)
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
@@ -53,7 +55,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     def _finish():
         l = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_scr[...] + jnp.log(l))[:, 0]
+        lse_ref[0, 0] = m_scr[...] + jnp.log(l)
 
 
 def decode_attention(
@@ -68,8 +70,7 @@ def decode_attention(
 
     ``interpret=None`` resolves from the platform (interpreter off-TPU only).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     B, H, D = q.shape
     KV, S = k_cache.shape[1], k_cache.shape[2]
     G = H // KV
@@ -79,7 +80,10 @@ def decode_attention(
     grid = (B * KV, n_k)
     scale = 1.0 / (D ** 0.5)
     qg = q.reshape(B, KV, G, D)
-    lens = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (1,))
+    # [1, 1] rather than [1]: under the scheduler's vmap over slots the
+    # SMEM block gains a leading squeezed dim, and the chip's tiling rule
+    # accepts a block only when its last two dims span the array.
+    lens = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (1, 1))
 
     out, lse = pl.pallas_call(
         functools.partial(_kernel, bk=bk, n_k=n_k, scale=scale),
@@ -92,11 +96,13 @@ def decode_attention(
         ],
         out_specs=[
             pl.BlockSpec((1, 1, G, D), lambda bh, kj: (bh // KV, bh % KV, 0, 0)),
-            pl.BlockSpec((1, 1, G), lambda bh, kj: (bh // KV, bh % KV, 0)),
+            # lse as a [G, 1] column: its block spans the last two dims
+            pl.BlockSpec((1, 1, G, 1),
+                         lambda bh, kj: (bh // KV, bh % KV, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
-            jax.ShapeDtypeStruct((B, KV, G), jnp.float32),
+            jax.ShapeDtypeStruct((B, KV, G, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),

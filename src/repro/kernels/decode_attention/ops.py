@@ -18,14 +18,10 @@ from typing import Optional
 
 import jax
 
+from repro.kernels import resolve_interpret
 from repro.kernels.decode_attention.decode_attention import decode_attention
 
-__all__ = ["decode_mha", "decode_mha_cache_size", "default_interpret"]
-
-
-def default_interpret() -> bool:
-    """Pallas interpreter only off-TPU (compiled dispatch on real hardware)."""
-    return jax.default_backend() != "tpu"
+__all__ = ["decode_mha", "decode_mha_cache_size"]
 
 
 @partial(jax.jit, static_argnames=("block_k", "interpret"))
@@ -37,10 +33,9 @@ def _decode_mha_jit(q, k_cache, v_cache, cache_len, *, block_k: int,
 
 def decode_mha(q, k_cache, v_cache, cache_len, *, block_k: int = 512,
                interpret: Optional[bool] = None):
-    if interpret is None:
-        interpret = default_interpret()
     return _decode_mha_jit(q, k_cache, v_cache, cache_len,
-                           block_k=block_k, interpret=interpret)
+                           block_k=block_k,
+                           interpret=resolve_interpret(interpret))
 
 
 def decode_mha_cache_size() -> int:

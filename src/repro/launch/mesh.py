@@ -17,34 +17,31 @@ __all__ = ["make_production_mesh", "make_mesh", "make_worker_mesh",
            "MeshAxes", "mesh_axes_of"]
 
 
-def _mesh_compat(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    if hasattr(jax.sharding, "AxisType"):  # jax ≥ 0.5: explicit axis types
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+def _make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...], devices=None):
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mesh_compat(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
     """Arbitrary mesh (tests use small CPU meshes)."""
-    return _mesh_compat(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_worker_mesh(n_devices: Optional[int] = None,
                      axis_name: str = "worker"):
     """1-D ``(worker,)`` mesh for the mesh-sharded FSI fleet backend
     (``pallas-bsr-sharded``): one mesh axis carrying the simulated-Lambda
-    dimension, sized to the host's devices by default.  Tests get >1 CPU
+    dimension, over the first ``n_devices`` devices (all by default).  Tests get >1 CPU
     devices via ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` set
     before the first jax init."""
-    n = n_devices or len(jax.devices())
-    return _mesh_compat((n,), (axis_name,))
+    devices = jax.devices()[:n_devices] if n_devices else jax.devices()
+    return _make_mesh((len(devices),), (axis_name,), devices=devices)
 
 
 class MeshAxes:

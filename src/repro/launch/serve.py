@@ -1,4 +1,5 @@
-"""Serving launcher (reduced configs on CPU; full configs via dry-run).
+"""Serving launcher: reduced configs by default, ``--full-size`` for the
+published widths (on a TPU the engine routes decode to ``pallas-splitk``).
 
     PYTHONPATH=src python -m repro.launch.serve --arch internlm2-1.8b \
         --batch 4 --prompt-len 16 --max-new 8
@@ -11,6 +12,7 @@ import argparse
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.serving.engine import ServingEngine
 
 
@@ -23,6 +25,7 @@ def main(argv=None):
     ap.add_argument("--full-size", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full_size:

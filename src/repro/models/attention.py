@@ -230,8 +230,12 @@ def decode_attention_dense(
     m = s.max(axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = p.sum(axis=-1, keepdims=True)
-    out = jnp.einsum("bkgqs,bksd->bqkgd", (p / jnp.maximum(l, 1e-30)).astype(v_cache.dtype),
-                     v_cache, preferred_element_type=ACC)
+    # Contract into the dot's natural [b, k, g, q, d] order and transpose
+    # after: XLA:CPU refuses some bf16 x bf16 -> f32 dots whose output
+    # transpose is folded into them ("Unsupported element type for
+    # DotThunk").
+    out = jnp.einsum("bkgqs,bksd->bkgqd", (p / jnp.maximum(l, 1e-30)).astype(v_cache.dtype),
+                     v_cache, preferred_element_type=ACC).transpose(0, 3, 1, 2, 4)
     if return_lse:
         lse = (m + jnp.log(jnp.maximum(l, 1e-30)))[..., 0, 0]  # [B, KV, G]
         return out.reshape(B, 1, H, D), lse.reshape(B, 1, H)

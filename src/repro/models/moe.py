@@ -296,13 +296,11 @@ def moe_ffn_shardmap(p: PyTree, x: jnp.ndarray, cfg: ModelConfig):
         out = jax.lax.psum(out.astype(ACC), model_axis)
         return out.astype(x_loc.dtype), lb
 
-    from repro.distributed.sharding import shard_map_compat
-
-    out, lb = shard_map_compat(
+    out, lb = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp_spec, None, None), P(), P(model_axis, None, None),
                   P(model_axis, None, None), P(model_axis, None, None)),
-        out_specs=(P(dp_spec, None, None), P()),
+        out_specs=(P(dp_spec, None, None), P()), check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
     if cfg.n_shared_experts:  # shared experts stay on the dense TP path

@@ -38,15 +38,14 @@ class GenerationResult:
 
 class ServingEngine:
     def __init__(self, cfg: ModelConfig, params: Optional[PyTree] = None,
-                 seed: int = 0, attn_backend=None, max_len_hint: int = 0,
+                 seed: int = 0, attn_backend=None,
                  engine: str = "device", pipeline_P: int = 2,
                  pipeline_channel: str = "queue"):
         """``attn_backend``: decode-attention backend name/instance routed to
         every model family's decode step (``repro.core.backends``).  ``None``
-        keeps the ``dense-ref`` oracle; ``"auto"`` asks the router for a
-        :class:`repro.serving.router.DecodePlan` — backend plus the
-        :class:`KVCacheLayout` its kernel-native caches need — from the
-        platform and ``max_len_hint`` (expected cache capacity).
+        asks the router (:func:`repro.serving.router.route_attention_backend`),
+        which picks from the platform: ``pallas-splitk`` on TPU, the
+        ``dense-ref`` oracle elsewhere.
 
         ``engine="fabric"`` serves over the serverless pipeline instead of
         on-device: the layer stack splits into ``pipeline_P`` stages whose
@@ -54,11 +53,10 @@ class ServingEngine:
         (:func:`repro.faas.lm_pipeline.run_lm_pipeline`); results carry the
         billing/clock telemetry in ``GenerationResult.fabric``."""
         self.cfg = cfg
-        if attn_backend == "auto":
-            from repro.serving.router import route_decode_plan
+        if attn_backend is None:
+            from repro.serving.router import route_attention_backend
 
-            attn_backend = route_decode_plan(
-                cfg, max_len=max_len_hint or None).attn_backend
+            attn_backend = route_attention_backend(cfg)
         if engine not in ("device", "fabric"):
             raise ValueError(f"unknown engine {engine!r}")
         self.engine = engine

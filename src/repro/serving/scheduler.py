@@ -199,8 +199,6 @@ class RequestScheduler:
             else:
                 from jax.sharding import PartitionSpec as P
 
-                from repro.distributed.sharding import shard_map_compat
-
                 def pspec(ax, leaf):
                     if ax is None:
                         return P()
@@ -211,13 +209,14 @@ class RequestScheduler:
                     pspec, seq_axes, paged, is_leaf=lambda x: x is None)
                 res_specs = jax.tree_util.tree_map(lambda _: P(), resident)
 
-                body = shard_map_compat(
+                body = jax.shard_map(
                     lambda p, t, r, g: jax.vmap(
                         lambda tok, res, pg: per_slot(
                             tok, res, pg, seq_shard_axes=axis))(t, r, g),
                     mesh=mesh,
                     in_specs=(P(), P(), res_specs, paged_specs),
                     out_specs=(P(), res_specs, paged_specs),
+                    check_vma=False,
                 )
                 logits, new_res, new_paged = body(params, tokens, resident,
                                                   paged)

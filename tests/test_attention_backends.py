@@ -294,8 +294,10 @@ class TestRegistry:
         from repro.serving.router import route_attention_backend
 
         cfg = get_config("internlm2-1.8b").reduced()
-        eng = ServingEngine(cfg, seed=0, attn_backend="auto")
+        eng = ServingEngine(cfg, seed=0)
         assert eng.attn_backend.name == route_attention_backend(cfg)
+        assert eng.attn_backend.name == (
+            "pallas-splitk" if jax.default_backend() == "tpu" else "dense-ref")
 
     def test_route_decode_plan_bundles_layout(self):
         """The router's DecodePlan carries the KVCacheLayout the backend's
@@ -335,10 +337,10 @@ class TestRegistry:
 
 class TestDecodeMhaJitCache:
     def test_interpret_default_resolved_from_platform(self):
-        from repro.kernels.decode_attention.ops import default_interpret
+        from repro.kernels import resolve_interpret
 
         # this suite runs on CPU/GPU hosts; on a real TPU the default flips
-        assert default_interpret() == (jax.default_backend() != "tpu")
+        assert resolve_interpret(None) == (jax.default_backend() != "tpu")
 
     def test_no_retrace_across_growing_cache_len(self):
         """One compiled entry serves the whole decode loop: cache_len is a

@@ -93,38 +93,23 @@ class TestFleetMegakernel:
         np.testing.assert_array_equal(host, grid)
 
     def test_count_bounded_grid_matches_static(self):
-        """The compiled-dispatch branch (count-bounded nested fori over
-        ``pl.ds`` slices) run under the interpreter on tiny shapes: padding
-        blocks are zero, so skipping them must be exact."""
-        import functools
-
-        from jax.experimental import pallas as pl
-
-        from repro.kernels.bsr_spmm.bsr_spmm import (
-            _fleet_kernel,
-            bsr_spmm_fleet_megakernel,
-        )
+        """The compiled kernel's K loop stops at each row's ``counts``: run
+        under the interpreter with NaN in every padding block, it must
+        never read them and must equal the static-K host lowering over
+        zero padding."""
+        from repro.kernels.bsr_spmm.bsr_spmm import bsr_spmm_fleet_megakernel
 
         blocks, cols, counts, x = self._fleet()
-        p, nbr, k_max, bm, bn = blocks.shape
-        n, b = x.shape[1:]
+        b = x.shape[2]
         want = np.asarray(bsr_spmm_fleet_megakernel(
             blocks, cols, counts, x, bias=-0.2, batch_block=b))
-        got = pl.pallas_call(
-            functools.partial(_fleet_kernel, bn=bn, k_max=k_max, bias=-0.2,
-                              clip=32.0, count_bounded=True),
-            grid=(p, 1),
-            in_specs=[
-                pl.BlockSpec((1, nbr), lambda w, j: (w, 0)),
-                pl.BlockSpec((1, nbr, k_max), lambda w, j: (w, 0, 0)),
-                pl.BlockSpec((1, nbr, k_max, bm, bn),
-                             lambda w, j: (w, 0, 0, 0, 0)),
-                pl.BlockSpec((1, n, b), lambda w, j: (w, 0, j)),
-            ],
-            out_specs=pl.BlockSpec((1, nbr * bm, b), lambda w, j: (w, 0, j)),
-            out_shape=jax.ShapeDtypeStruct((p, nbr * bm, b), jnp.float32),
-            interpret=True,
-        )(counts, cols, blocks, x)
+        k = np.arange(blocks.shape[2])
+        pad = k[None, None, :] >= np.asarray(counts)[..., None]
+        poisoned = jnp.where(jnp.asarray(pad)[..., None, None], jnp.nan,
+                             blocks)
+        got = bsr_spmm_fleet_megakernel(
+            poisoned, cols, counts, x, bias=-0.2, batch_block=b,
+            interpret=True, force_grid=True)
         np.testing.assert_array_equal(np.asarray(got), want)
 
     def test_matches_per_worker_kernel(self):
