@@ -33,7 +33,8 @@ counts, and per-worker clock charges are bit-identical by construction
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, List, Literal, Optional, Sequence, Tuple, Union
+from typing import (Any, Callable, Dict, Iterator, List, Literal, Optional,
+                    Sequence, Tuple, Union)
 
 import numpy as np
 
@@ -41,6 +42,7 @@ from repro.core.backends import ComputeBackend, get_backend
 from repro.core.partitioner import PartitionResult
 from repro.core.send_recv import LayerCommPlan
 from repro.core.sparse import CSRMatrix
+from repro.core.spans import span, spanned
 from repro.data.graphchallenge import GraphChallengeNet
 from repro.faas.object_service import ObjectFabric
 from repro.faas.payload import Chunk, decode_chunk, pack_rows_fleet
@@ -241,6 +243,22 @@ def _send_jobs(
     return jobs, targets
 
 
+def _fleet_pack(
+    arts: Sequence[WorkerLayerArtifact], x_panels: Sequence[np.ndarray],
+    workers: Sequence[WorkerState], max_payload: int, exploit_sparsity: bool,
+) -> Tuple[Iterator[List[Chunk]], List[List[int]]]:
+    """Every worker's pack jobs for one layer in one ``pack_rows_fleet``
+    call: the lazy per-job chunk lists (in rank, then target order) and each
+    worker's targets."""
+    jobs: List[tuple] = []
+    fleet_targets: List[List[int]] = []
+    for art, x_prev, worker in zip(arts, x_panels, workers):
+        wjobs, targets = _send_jobs(art, x_prev, worker.rank, exploit_sparsity)
+        jobs.extend(wjobs)
+        fleet_targets.append(targets)
+    return pack_rows_fleet(jobs, max_payload), fleet_targets
+
+
 def _collect_entries(
     art: WorkerLayerArtifact, rank: int, batch: int,
     packed: Sequence[Tuple[int, List[Chunk]]],
@@ -291,6 +309,7 @@ def _batch_publish_entries(
     return batches
 
 
+@spanned("fsi.publish")
 def _queue_publish_entries(
     entries: List[Tuple[int, Chunk]], worker: WorkerState, fabric: QueueFabric,
     compute: ComputeModel, raw_total: int, send_threads: int,
@@ -325,6 +344,7 @@ def _queue_publish_entries(
         worker.advance_to_abs(max(lane_time))
 
 
+@spanned("fsi.publish")
 def _object_put_targets(
     art: WorkerLayerArtifact, rank: int,
     packed: Sequence[Tuple[int, List[Chunk]]], worker: WorkerState,
@@ -386,6 +406,20 @@ class FleetRecvBuffers:
         return cls(flat=flat, offsets=offsets, views=views)
 
 
+@spanned("fsi.local")
+def _local_overlap(
+    art: WorkerLayerArtifact, x_prev: np.ndarray, worker: WorkerState,
+    compute: ComputeModel, batch: int,
+) -> np.ndarray:
+    """Line 8 / line 9 for one worker: its compact input buffer holding the
+    locally-owned rows, then the local-MVP charge."""
+    x_buf = np.zeros((len(art.needed_rows), batch), dtype=np.float32)
+    x_buf[art.owned_positions] = x_prev[art.owned_source_positions]
+    worker.charge_compute(art.local_flops * batch, compute)
+    return x_buf
+
+
+@spanned("fsi.local")
 def _fleet_local_overlap(
     arts: Sequence[WorkerLayerArtifact], x_panels: Sequence[np.ndarray],
     workers: Sequence[WorkerState], compute: ComputeModel, batch: int,
@@ -427,18 +461,16 @@ def fsi_queue_send_and_local(
     """
     batch = x_prev.shape[1] if x_prev.ndim == 2 else 1
     # ---- lines 3-7: extract rows, pack byte strings, publish batches -------
-    jobs, targets = _send_jobs(art, x_prev, worker.rank, exploit_sparsity)
-    packed = list(zip(targets, pack_rows_fleet(
-        jobs, fabric.pricing.max_publish_payload)))
-    entries, raw_total = _collect_entries(art, worker.rank, batch, packed)
-    _queue_publish_entries(entries, worker, fabric, compute, raw_total,
-                           send_threads)
+    with span("fsi.send"):
+        jobs, targets = _send_jobs(art, x_prev, worker.rank, exploit_sparsity)
+        packed = list(zip(targets, pack_rows_fleet(
+            jobs, fabric.pricing.max_publish_payload)))
+        entries, raw_total = _collect_entries(art, worker.rank, batch, packed)
+        _queue_publish_entries(entries, worker, fabric, compute, raw_total,
+                               send_threads)
 
     # ---- line 8: local MVP overlapped with in-flight communication --------
-    x_buf = np.zeros((len(art.needed_rows), batch), dtype=np.float32)
-    x_buf[art.owned_positions] = x_prev[art.owned_source_positions]
-    worker.charge_compute(art.local_flops * batch, compute)
-    return x_buf
+    return _local_overlap(art, x_prev, worker, compute, batch)
 
 
 def fsi_queue_send_and_local_fleet(
@@ -457,18 +489,16 @@ def fsi_queue_send_and_local_fleet(
     rank order — byte streams, publish batching, and clock charges are
     bit-identical to P ``fsi_queue_send_and_local`` calls."""
     batch = x_panels[0].shape[1]
-    jobs: List[tuple] = []
-    fleet_targets: List[List[int]] = []
-    for art, x_prev, worker in zip(arts, x_panels, workers):
-        wjobs, targets = _send_jobs(art, x_prev, worker.rank, exploit_sparsity)
-        jobs.extend(wjobs)
-        fleet_targets.append(targets)
-    packed_iter = pack_rows_fleet(jobs, fabric.pricing.max_publish_payload)
-    for art, worker, targets in zip(arts, workers, fleet_targets):
-        packed = [(t, next(packed_iter)) for t in targets]
-        entries, raw_total = _collect_entries(art, worker.rank, batch, packed)
-        _queue_publish_entries(entries, worker, fabric, compute, raw_total,
-                               send_threads)
+    with span("fsi.send"):
+        packed_iter, fleet_targets = _fleet_pack(
+            arts, x_panels, workers, fabric.pricing.max_publish_payload,
+            exploit_sparsity)
+        for art, worker, targets in zip(arts, workers, fleet_targets):
+            packed = [(t, next(packed_iter)) for t in targets]
+            entries, raw_total = _collect_entries(art, worker.rank, batch,
+                                                  packed)
+            _queue_publish_entries(entries, worker, fabric, compute,
+                                   raw_total, send_threads)
     return _fleet_local_overlap(arts, x_panels, workers, compute, batch)
 
 
@@ -582,6 +612,7 @@ def _queue_drain_one(
             worker.advance_to_abs(fabric.delete_batch(worker.rank, receipts, worker.abs_time))
 
 
+@spanned("fsi.recv")
 def fsi_queue_recv(
     art: WorkerLayerArtifact,
     x_buf: np.ndarray,
@@ -601,6 +632,7 @@ def fsi_queue_recv(
     return x_buf
 
 
+@spanned("fsi.recv")
 def fsi_queue_recv_fleet(
     arts: Sequence[WorkerLayerArtifact],
     bufs: FleetRecvBuffers,
@@ -667,16 +699,14 @@ def fsi_object_send_and_local(
     # ---- lines 3-8: one object (or .nul) per target ------------------------
     # Empty payloads (all mapped rows zero under activation sparsity) become
     # 0-byte `.nul` markers, which readers retire without a GET (lines 4-5).
-    jobs, targets = _send_jobs(art, x_prev, worker.rank, exploit_sparsity)
-    packed = list(zip(targets, pack_rows_fleet(jobs, max_object_part)))
-    _object_put_targets(art, worker.rank, packed, worker, fabric, compute,
-                        io_threads)
+    with span("fsi.send"):
+        jobs, targets = _send_jobs(art, x_prev, worker.rank, exploit_sparsity)
+        packed = list(zip(targets, pack_rows_fleet(jobs, max_object_part)))
+        _object_put_targets(art, worker.rank, packed, worker, fabric, compute,
+                            io_threads)
 
     # ---- line 9: local MVP overlap -----------------------------------------
-    x_buf = np.zeros((len(art.needed_rows), batch), dtype=np.float32)
-    x_buf[art.owned_positions] = x_prev[art.owned_source_positions]
-    worker.charge_compute(art.local_flops * batch, compute)
-    return x_buf
+    return _local_overlap(art, x_prev, worker, compute, batch)
 
 
 def fsi_object_send_and_local_fleet(
@@ -693,17 +723,13 @@ def fsi_object_send_and_local_fleet(
     """Algorithm 2 lines 3-9 for the whole fleet: one batched pack, then each
     worker's PUTs in rank order — billing-identical to the per-worker path."""
     batch = x_panels[0].shape[1]
-    jobs: List[tuple] = []
-    fleet_targets: List[List[int]] = []
-    for art, x_prev, worker in zip(arts, x_panels, workers):
-        wjobs, targets = _send_jobs(art, x_prev, worker.rank, exploit_sparsity)
-        jobs.extend(wjobs)
-        fleet_targets.append(targets)
-    packed_iter = pack_rows_fleet(jobs, max_object_part)
-    for art, worker, targets in zip(arts, workers, fleet_targets):
-        packed = [(t, next(packed_iter)) for t in targets]
-        _object_put_targets(art, worker.rank, packed, worker, fabric, compute,
-                            io_threads)
+    with span("fsi.send"):
+        packed_iter, fleet_targets = _fleet_pack(
+            arts, x_panels, workers, max_object_part, exploit_sparsity)
+        for art, worker, targets in zip(arts, workers, fleet_targets):
+            packed = [(t, next(packed_iter)) for t in targets]
+            _object_put_targets(art, worker.rank, packed, worker, fabric,
+                                compute, io_threads)
     return _fleet_local_overlap(arts, x_panels, workers, compute, batch)
 
 
@@ -764,6 +790,7 @@ def _object_drain_one(
             worker.charge_seconds(fabric.list_latency)
 
 
+@spanned("fsi.recv")
 def fsi_object_recv(
     art: WorkerLayerArtifact,
     x_buf: np.ndarray,
@@ -780,6 +807,7 @@ def fsi_object_recv(
     return x_buf
 
 
+@spanned("fsi.recv")
 def fsi_object_recv_fleet(
     arts: Sequence[WorkerLayerArtifact],
     bufs: FleetRecvBuffers,

@@ -31,6 +31,8 @@ from typing import Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core.spans import span
+
 __all__ = ["encode_chunk", "decode_chunk", "pack_rows", "pack_rows_fleet",
            "Chunk"]
 
@@ -76,11 +78,13 @@ def encode_chunk(
     if not compress:
         return header + bytes(ids_buf) + bytes(val_buf)
     # stream the pieces through one compressobj: no concatenated body temp
-    co = _pool.fresh() if _pool is not None else zlib.compressobj(_ZLIB_LEVEL)
-    return b"".join(
-        (co.compress(header), co.compress(ids_buf), co.compress(val_buf),
-         co.flush())
-    )
+    with span("payload.compress"):
+        co = (_pool.fresh() if _pool is not None
+              else zlib.compressobj(_ZLIB_LEVEL))
+        return b"".join(
+            (co.compress(header), co.compress(ids_buf),
+             co.compress(val_buf), co.flush())
+        )
 
 
 def decode_chunk(blob: bytes, compressed: bool = True) -> Tuple[int, int, np.ndarray, np.ndarray, int, int]:
@@ -88,7 +92,11 @@ def decode_chunk(blob: bytes, compressed: bool = True) -> Tuple[int, int, np.nda
     into the (decompressed) body — they stay valid as long as the caller
     holds them, and any mutation must copy first (the recv scatter is the
     one site that materializes them, into the destination buffer)."""
-    body = zlib.decompress(blob) if compressed else blob
+    if compressed:
+        with span("payload.decompress"):
+            body = zlib.decompress(blob)
+    else:
+        body = blob
     layer, src, n_rows, batch, seq, total = _HEADER.unpack_from(body, 0)
     off = _HEADER.size
     row_ids = np.frombuffer(body, dtype=np.int32, count=n_rows, offset=off)

@@ -53,6 +53,7 @@ from repro.core.fsi import (
 )
 from repro.core.partitioner import PartitionResult, partition_network
 from repro.core.send_recv import build_comm_plans
+from repro.core.spans import span, spanned
 from repro.data.graphchallenge import GraphChallengeNet
 from repro.faas.chaos import ChaosState, FaultPlan, FleetFailure
 from repro.faas.collectives import reduce_to_root
@@ -171,6 +172,7 @@ def charge_weight_load(worker: WorkerState, artifact, latency: "LatencyModel") -
         worker.ledger.sync(s)
 
 
+@spanned("fsi.call")
 def run_fsi(
     net: GraphChallengeNet,
     x0: np.ndarray,
@@ -273,27 +275,30 @@ def run_fsi(
 
     # ---------------- offline partitioning + plans --------------------------
     if partition is None:
-        partition = partition_network(net.layers, P, method=partition_method, seed=seed)
-    plans = build_comm_plans(net.layers, partition)
-    artifacts = prepare_worker_artifacts(net.layers, partition, plans,
-                                         backend=backend)
-    # Fleet batching: pallas-bsr stacks each layer's per-worker operands so
-    # one device dispatch serves all P workers; pallas-bsr-sharded lays that
-    # stack over a `worker` mesh axis (shard_map, blocked P/D per device);
-    # numpy backends return None and finish per worker.
-    fleet_states = backend.fleet_prepare_all(
-        [[artifacts[m].layers[k].state_for(backend) for m in range(P)]
-         for k in range(net.n_layers)]
-    )
-
+        with span("fsi.partition"):
+            partition = partition_network(net.layers, P,
+                                          method=partition_method, seed=seed)
+    with span("fsi.plans"):
+        plans = build_comm_plans(net.layers, partition)
     memory_mb = memory_mb or _default_memory_mb(net.neurons)
-    for a in artifacts:
-        need = a.memory_bytes(batch)
-        if need > memory_mb * 1024 * 1024:
-            raise MemoryError(
-                f"worker {a.rank} shard needs ~{need/1e6:.0f}MB > {memory_mb}MB; "
-                f"increase P or memory"
-            )
+    with span("fsi.prepare"):
+        artifacts = prepare_worker_artifacts(net.layers, partition, plans,
+                                             backend=backend)
+        # Fleet batching: pallas-bsr stacks each layer's per-worker operands
+        # so one device dispatch serves all P workers; pallas-bsr-sharded
+        # lays that stack over a `worker` mesh axis (shard_map, blocked P/D
+        # per device); numpy backends return None and finish per worker.
+        fleet_states = backend.fleet_prepare_all(
+            [[artifacts[m].layers[k].state_for(backend) for m in range(P)]
+             for k in range(net.n_layers)]
+        )
+        for a in artifacts:
+            need = a.memory_bytes(batch)
+            if need > memory_mb * 1024 * 1024:
+                raise MemoryError(
+                    f"worker {a.rank} shard needs ~{need/1e6:.0f}MB > "
+                    f"{memory_mb}MB; increase P or memory"
+                )
 
     # ---------------- launch tree -------------------------------------------
     provision_s: Optional[np.ndarray] = None
@@ -391,83 +396,92 @@ def run_fsi(
         x0[artifacts[m].x0_rows].astype(np.float32) for m in range(P)
     ]
     for k in range(net.n_layers):
-        t_before = [w.clock for w in workers]
-        arts_k = [artifacts[m].layers[k] for m in range(P)]
-        ch_k = plan_channels[k]
-        fabric = fabrics[ch_k]
-        if chaos is not None:
-            # Crash-fault path: per-worker handlers with kill sites, panel
-            # checkpoints, and re-invoke recovery (see _chaos_run_layer).
-            x_panels = _chaos_run_layer(
-                k, net, artifacts, x_panels, workers, fabrics, plan_channels,
-                backend, compute, latency, chaos, ckpt_fabric, sim.warm_pool,
-                spare_provision_s, runtime_start, exploit_sparsity,
-            )
-            _check_stragglers(
-                reinvoke_stragglers, workers, t_before, straggler_timeout,
-                artifacts, latency, sim.warm_pool, spare_provision_s)
-            continue
-        # Phases 1+2 — publish + overlapped local MVP, then drain the channel.
-        # ``channel_batching`` (the default) runs the fleet-batched host path:
-        # one pack pass and one vectorized drain scatter per layer instead of
-        # O(P) Python-level passes.  Billed charges are bit-identical either
-        # way (the fleet variants share the publish/drain helpers — asserted
-        # in tests/test_fleet_channels.py).
-        bufs: List[np.ndarray]
-        if channel_batching:
-            if ch_k == "queue":
-                fleet_bufs = fsi_queue_send_and_local_fleet(
-                    arts_k, x_panels, workers, fabric, compute,
-                    exploit_sparsity=exploit_sparsity,
+        with span("fsi.layer", layer=k):
+            t_before = [w.clock for w in workers]
+            arts_k = [artifacts[m].layers[k] for m in range(P)]
+            ch_k = plan_channels[k]
+            fabric = fabrics[ch_k]
+            if chaos is not None:
+                # Crash-fault path: per-worker handlers with kill sites,
+                # panel checkpoints, and re-invoke recovery (see
+                # _chaos_run_layer).
+                x_panels = _chaos_run_layer(
+                    k, net, artifacts, x_panels, workers, fabrics,
+                    plan_channels, backend, compute, latency, chaos,
+                    ckpt_fabric, sim.warm_pool, spare_provision_s,
+                    runtime_start, exploit_sparsity,
                 )
-                bufs = fsi_queue_recv_fleet(arts_k, fleet_bufs, workers,
-                                            fabric, compute)
+                with span("fsi.finish"):
+                    _check_stragglers(
+                        reinvoke_stragglers, workers, t_before,
+                        straggler_timeout, artifacts, latency, sim.warm_pool,
+                        spare_provision_s)
+                continue
+            # Phases 1+2 — publish + overlapped local MVP, then drain the
+            # channel.  ``channel_batching`` (the default) runs the
+            # fleet-batched host path: one pack pass and one vectorized drain
+            # scatter per layer instead of O(P) Python-level passes.  Billed
+            # charges are bit-identical either way (the fleet variants share
+            # the publish/drain helpers — asserted in
+            # tests/test_fleet_channels.py).
+            bufs: List[np.ndarray]
+            if channel_batching:
+                if ch_k == "queue":
+                    fleet_bufs = fsi_queue_send_and_local_fleet(
+                        arts_k, x_panels, workers, fabric, compute,
+                        exploit_sparsity=exploit_sparsity,
+                    )
+                    bufs = fsi_queue_recv_fleet(arts_k, fleet_bufs, workers,
+                                                fabric, compute)
+                else:
+                    fleet_bufs = fsi_object_send_and_local_fleet(
+                        arts_k, x_panels, workers, fabric, compute,
+                        exploit_sparsity=exploit_sparsity,
+                    )
+                    bufs = fsi_object_recv_fleet(arts_k, fleet_bufs, workers,
+                                                 fabric, compute)
             else:
-                fleet_bufs = fsi_object_send_and_local_fleet(
-                    arts_k, x_panels, workers, fabric, compute,
-                    exploit_sparsity=exploit_sparsity,
-                )
-                bufs = fsi_object_recv_fleet(arts_k, fleet_bufs, workers,
-                                             fabric, compute)
-        else:
-            bufs = []
-            for m in range(P):
-                art = arts_k[m]
-                if ch_k == "queue":
-                    bufs.append(fsi_queue_send_and_local(
-                        art, x_panels[m], workers[m], fabric, compute,
-                        exploit_sparsity=exploit_sparsity,
-                    ))
+                bufs = []
+                for m in range(P):
+                    art = arts_k[m]
+                    if ch_k == "queue":
+                        bufs.append(fsi_queue_send_and_local(
+                            art, x_panels[m], workers[m], fabric, compute,
+                            exploit_sparsity=exploit_sparsity,
+                        ))
+                    else:
+                        bufs.append(fsi_object_send_and_local(
+                            art, x_panels[m], workers[m], fabric, compute,
+                            exploit_sparsity=exploit_sparsity,
+                        ))
+                for m in range(P):
+                    art = arts_k[m]
+                    if ch_k == "queue":
+                        bufs[m] = fsi_queue_recv(art, bufs[m], workers[m],
+                                                 fabric, compute)
+                    else:
+                        bufs[m] = fsi_object_recv(art, bufs[m], workers[m],
+                                                  fabric, compute)
+            with span("fsi.apply"):
+                if fleet_states is not None:
+                    outs = backend.fleet_apply(fleet_states[k], bufs,
+                                               net.bias)
                 else:
-                    bufs.append(fsi_object_send_and_local(
-                        art, x_panels[m], workers[m], fabric, compute,
-                        exploit_sparsity=exploit_sparsity,
-                    ))
-            for m in range(P):
-                art = arts_k[m]
-                if ch_k == "queue":
-                    bufs[m] = fsi_queue_recv(art, bufs[m], workers[m], fabric, compute)
-                else:
-                    bufs[m] = fsi_object_recv(art, bufs[m], workers[m], fabric, compute)
-        if fleet_states is not None:
-            outs = backend.fleet_apply(fleet_states[k], bufs, net.bias)
-        else:
-            outs = [
-                backend.apply(
-                    artifacts[m].layers[k].state_for(backend), bufs[m], net.bias
-                )
-                for m in range(P)
-            ]
-        for m in range(P):
-            x_panels[m] = charge_finish(
-                artifacts[m].layers[k], bufs[m], outs[m], workers[m], compute
-            )
-        # Straggler slowdown applies to *active* work (compute, pack/unpack)
-        # via WorkerState.slowdown at the charge sites — never to channel
-        # waits, which would compound across the fleet.
-        _check_stragglers(
-            reinvoke_stragglers, workers, t_before, straggler_timeout,
-            artifacts, latency, sim.warm_pool, spare_provision_s)
+                    outs = _apply_each(backend, artifacts, k, bufs, net.bias)
+            with span("fsi.finish"):
+                for m in range(P):
+                    x_panels[m] = charge_finish(
+                        artifacts[m].layers[k], bufs[m], outs[m], workers[m],
+                        compute
+                    )
+                # Straggler slowdown applies to *active* work (compute,
+                # pack/unpack) via WorkerState.slowdown at the charge sites —
+                # never to channel waits, which would compound across the
+                # fleet.
+                _check_stragglers(
+                    reinvoke_stragglers, workers, t_before,
+                    straggler_timeout, artifacts, latency, sim.warm_pool,
+                    spare_provision_s)
 
     if chaos is not None:
         # Mailbox sweep: a worker recovered at the *last* layer re-published
@@ -830,16 +844,27 @@ def _chaos_run_layer(
             bufs[m] = drain(m)
         else:
             bufs[m] = drain(m)
-    outs = [
-        backend.apply(artifacts[m].layers[k].state_for(backend), bufs[m],
-                      net.bias)
-        for m in range(P)
-    ]
-    return [
-        charge_finish(artifacts[m].layers[k], bufs[m], outs[m], workers[m],
-                      compute)
-        for m in range(P)
-    ]
+    with span("fsi.apply"):
+        outs = _apply_each(backend, artifacts, k, bufs, net.bias)
+    with span("fsi.finish"):
+        return [
+            charge_finish(artifacts[m].layers[k], bufs[m], outs[m],
+                          workers[m], compute)
+            for m in range(P)
+        ]
+
+
+def _apply_each(
+    backend: ComputeBackend,
+    artifacts: List[WorkerArtifacts],
+    k: int,
+    bufs: List[np.ndarray],
+    bias: float,
+) -> List[np.ndarray]:
+    """Layer ``k`` worker by worker: the backends that batch no fleet."""
+    return [backend.apply(artifacts[m].layers[k].state_for(backend), bufs[m],
+                          bias)
+            for m in range(len(bufs))]
 
 
 def _autotune_plan(

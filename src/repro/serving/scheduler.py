@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.backends import KVCacheLayout
+from repro.core.spans import span, spanned
 from repro.serving.kv_pool import (
     KVBlockPool, RESERVED_BLOCKS, merge_cache, split_cache)
 
@@ -81,6 +82,7 @@ class _Slot:
     n_blocks: int
     tokens: List[int]
     admitted_step: int
+    prefix: int                        # cache positions the prefill wrote
 
 
 class RequestScheduler:
@@ -146,6 +148,13 @@ class RequestScheduler:
         self._step_fn = self._build_step()
         self.steps_run = 0          # decode steps executed (bench: utilization)
         self.tokens_emitted = 0
+        # Per decode step, summed: the cache positions the active slots
+        # attend over, and the num_slots x slot_capacity positions
+        # ``pool.gather`` materializes.  Their ratio is the share of the
+        # gathered cache that holds valid positions; each step's pair also
+        # rides on its ``serve.step`` span (``valid=``, ``capacity=``).
+        self.decode_positions = 0
+        self.capacity_positions = 0
 
     # ------------------------------------------------------------------ #
 
@@ -184,7 +193,8 @@ class RequestScheduler:
 
         def step(params, tokens, resident, buffers, tables, active):
             positions = resident["length"]                    # [slots]
-            paged = pool.gather(buffers, tables)
+            with jax.named_scope("serve.pool_gather"):
+                paged = pool.gather(buffers, tables)
 
             def per_slot(tok, res, pg, **kw):
                 cache = merge_cache(pg, res, seq_axes)
@@ -194,8 +204,9 @@ class RequestScheduler:
                 return logits, new_res, new_pg
 
             if mesh is None:
-                logits, new_res, new_paged = jax.vmap(per_slot)(
-                    tokens, resident, paged)
+                with jax.named_scope("serve.decode"):
+                    logits, new_res, new_paged = jax.vmap(per_slot)(
+                        tokens, resident, paged)
             else:
                 from jax.sharding import PartitionSpec as P
 
@@ -218,16 +229,19 @@ class RequestScheduler:
                     out_specs=(P(), res_specs, paged_specs),
                     check_vma=False,
                 )
-                logits, new_res, new_paged = body(params, tokens, resident,
-                                                  paged)
+                with jax.named_scope("serve.decode"):
+                    logits, new_res, new_paged = body(params, tokens,
+                                                      resident, paged)
 
-            chunks = chunks_at(new_paged, positions)
-            buffers = pool.scatter_token(buffers, chunks,
-                                         tables, positions, active)
+            with jax.named_scope("serve.pool_scatter"):
+                chunks = chunks_at(new_paged, positions)
+                buffers = pool.scatter_token(buffers, chunks,
+                                             tables, positions, active)
             # logits: [slots, 1, 1, V].  The greedy argmax matches the static
             # path's per-request `argmax(logits[:, -1:], -1)` elementwise.
-            next_tok = jnp.argmax(logits[..., -1:, :], axis=-1) \
-                .astype(jnp.int32)                       # [slots, 1, 1]
+            with jax.named_scope("serve.sample"):
+                next_tok = jnp.argmax(logits[..., -1:, :], axis=-1) \
+                    .astype(jnp.int32)                   # [slots, 1, 1]
             return logits[:, 0, -1], next_tok, new_res, buffers
 
         # Donate the big rotating state: slot-resident stacks + pool pages.
@@ -243,13 +257,16 @@ class RequestScheduler:
         batch: Dict[str, Any] = {"tokens": jnp.asarray(prompt)}
         if req.extra:
             batch.update({k: jnp.asarray(v) for k, v in req.extra.items()})
-        logits, cache = self._prefill(self.params, batch, self.slot_capacity)
-        need = (prompt.shape[1] + req.max_new_tokens
-                + (self.model.cfg.frontend_tokens or 0))
+        with span("serve.prefill", rid=req.rid):
+            logits, cache = self._prefill(self.params, batch,
+                                          self.slot_capacity)
+        frontend = self.model.cfg.frontend_tokens or 0
+        need = prompt.shape[1] + req.max_new_tokens + frontend
         n_blocks = (self.layout.blocks_for(need)
                     if self.pool.table_width else 0)
         paged, resident = split_cache(cache, self.seq_axes)
-        table = self.pool.admit(paged, need)     # may raise PoolExhausted
+        with span("serve.pool_admit", rid=req.rid):
+            table = self.pool.admit(paged, need)  # may raise PoolExhausted
         self._tables[slot] = table
         self._resident = jax.tree_util.tree_map(
             lambda ax, st, leaf: (st if ax is not None else
@@ -261,9 +278,12 @@ class RequestScheduler:
         self._active[slot] = True
         self._tables_dev = jnp.asarray(self._tables)
         self._active_dev = jnp.asarray(self._active)
+        # The vlm's frontend embeddings lead its prompt in the cache.
+        prefix = prompt.shape[1] + (
+            frontend if self.model.cfg.family == "vlm" else 0)
         self._slots[slot] = _Slot(request=req, table=table,
                                   n_blocks=n_blocks, tokens=[],
-                                  admitted_step=step_idx)
+                                  admitted_step=step_idx, prefix=prefix)
 
     def _can_admit(self, req: Request) -> bool:
         if not (~self._active).any():
@@ -294,6 +314,7 @@ class RequestScheduler:
 
     # ------------------------------------------------------------------ #
 
+    @spanned("serve.run")
     def run(self, requests: Sequence[Request],
             max_steps: Optional[int] = None) -> List[RequestResult]:
         """Serve the whole stream; returns results ordered by completion."""
@@ -318,15 +339,25 @@ class RequestScheduler:
             # FIFO admission of every arrived request that fits right now.
             while queue and queue[0].arrival <= step_idx \
                     and self._can_admit(queue[0]):
-                self._admit(queue.pop(0), step_idx)
+                req = queue.pop(0)
+                with span("serve.admit", rid=req.rid):
+                    self._admit(req, step_idx)
             if not self._active.any():
                 step_idx += 1           # idle tick: waiting on a future arrival
                 continue
-            input_tokens = np.asarray(self._tokens)[:, 0, 0]
-            logits, next_tok, self._resident, self.pool.buffers = \
-                self._step_fn(self.params, self._tokens, self._resident,
-                              self.pool.buffers, self._tables_dev,
-                              self._active_dev)
+            with span("serve.token_wait"):
+                input_tokens = np.asarray(self._tokens)[:, 0, 0]
+            valid = sum(st.prefix + len(st.tokens) + 1
+                        for st in self._slots if st is not None)
+            capacity = self.num_slots * self.slot_capacity
+            self.decode_positions += valid
+            self.capacity_positions += capacity
+            with span("serve.step", step=self.steps_run, valid=valid,
+                      capacity=capacity):
+                logits, next_tok, self._resident, self.pool.buffers = \
+                    self._step_fn(self.params, self._tokens, self._resident,
+                                  self.pool.buffers, self._tables_dev,
+                                  self._active_dev)
             self._tokens = next_tok
             self.steps_run += 1
             logits_np = None
@@ -337,8 +368,10 @@ class RequestScheduler:
                 st.tokens.append(int(input_tokens[slot]))
                 self.tokens_emitted += 1
                 if len(st.tokens) == st.request.max_new_tokens:
-                    if logits_np is None:
-                        logits_np = np.asarray(logits)
-                    self._retire(slot, logits_np[slot], step_idx, results)
+                    with span("serve.retire", rid=st.request.rid):
+                        if logits_np is None:
+                            logits_np = np.asarray(logits)
+                        self._retire(slot, logits_np[slot], step_idx,
+                                     results)
             step_idx += 1
         return results
