@@ -585,6 +585,40 @@ class AttentionBackend(Protocol):
         cross-shard ``combine_split_kv`` merge."""
         ...
 
+    def decode_paged(
+        self,
+        q: Any,          # [slots, 1, H, D] — each slot's new token
+        k_pages: Any,    # [L, 1, KV, num_blocks, bk, D] — the paged pool
+        v_pages: Any,
+        tables: Any,     # int32 [slots, W] — each slot's physical pages
+        lengths: Any,    # int32 [slots] — each slot's valid positions
+        layer: Any,      # int32 [] — the pool's layer to read
+    ) -> Any:
+        """Attention of each slot over its pages of one layer of the paged
+        KV pool (``serving/kv_pool.py``); returns [slots, 1, H, D] in
+        ``q.dtype``, bitwise what :meth:`decode` gives on each slot's
+        gathered cache."""
+        ...
+
+
+def _decode_gathered_pages(decode, q, k_pages, v_pages, tables, lengths,
+                           layer):
+    """The jnp paged entry: gather one layer's pages of every slot into a
+    contiguous ``[1, KV, W * bk, D]`` cache and run ``decode`` per slot, as
+    the gathering scheduler step runs it."""
+    import jax
+    import jax.numpy as jnp
+
+    def slot_cache(pages, table):
+        x = jnp.take(pages[layer], table, axis=-3)   # [1, KV, W, bk, D]
+        return x.reshape(x.shape[:-3] + (-1, x.shape[-1]))
+
+    def one(q1, table, n):
+        return decode(q1[None], slot_cache(k_pages, table),
+                      slot_cache(v_pages, table), n)[0]
+
+    return jax.vmap(one)(q, tables, lengths)
+
 
 class DenseRefAttention:
     """``decode_attention_dense`` — the parity oracle for the registry.
@@ -613,6 +647,10 @@ class DenseRefAttention:
 
         return decode_attention_dense(q, k_cache, v_cache, cache_len,
                                       return_lse=True)
+
+    def decode_paged(self, q, k_pages, v_pages, tables, lengths, layer):
+        return _decode_gathered_pages(self.decode, q, k_pages, v_pages,
+                                      tables, lengths, layer)
 
 
 class ChunkedLseAttention:
@@ -647,6 +685,10 @@ class ChunkedLseAttention:
             return_lse=True,
         )
 
+    def decode_paged(self, q, k_pages, v_pages, tables, lengths, layer):
+        return _decode_gathered_pages(self.decode, q, k_pages, v_pages,
+                                      tables, lengths, layer)
+
 
 # (padded cache length upper bound, block_k) — smallest block that keeps the
 # kv sweep ≥ a few blocks deep without padding tiny caches to 512.
@@ -674,6 +716,8 @@ class PallasSplitKAttention:
     """
 
     name = "pallas-splitk"
+    # decode_paged fetches each slot's pages up to its last valid one only
+    skips_invalid_pages = True
 
     def __init__(self, block_k: Optional[int] = None,
                  interpret: Optional[bool] = None):
@@ -720,6 +764,23 @@ class PallasSplitKAttention:
             block_k=bk, interpret=self.interpret,
         )
         return out[:, None], lse[:, None]
+
+    def decode_paged(self, q, k_pages, v_pages, tables, lengths, layer):
+        """The paged split-K kernel: one page is one kernel block, so the
+        pool's page size must be the block this backend picks for the
+        tables' capacity."""
+        from repro.kernels.decode_attention.ops import decode_mha_paged
+
+        bk, W = k_pages.shape[-2], tables.shape[1]
+        if bk != self.block_k_for(W * bk):
+            raise ValueError(
+                f"pool pages of {bk} positions, but {self.name} reads a "
+                f"capacity of {W * bk} in blocks of {self.block_k_for(W * bk)}")
+        B, _, H, D = q.shape
+        out, _ = decode_mha_paged(q.reshape(B, H, D), k_pages, v_pages,
+                                  tables, lengths, layer,
+                                  interpret=self.interpret)
+        return out[:, None].astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

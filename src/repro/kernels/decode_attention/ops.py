@@ -19,9 +19,10 @@ from typing import Optional
 import jax
 
 from repro.kernels import resolve_interpret
-from repro.kernels.decode_attention.decode_attention import decode_attention
+from repro.kernels.decode_attention.decode_attention import (
+    decode_attention, paged_decode_attention)
 
-__all__ = ["decode_mha", "decode_mha_cache_size"]
+__all__ = ["decode_mha", "decode_mha_paged", "decode_mha_cache_size"]
 
 
 @partial(jax.jit, static_argnames=("block_k", "interpret"))
@@ -36,6 +37,22 @@ def decode_mha(q, k_cache, v_cache, cache_len, *, block_k: int = 512,
     return _decode_mha_jit(q, k_cache, v_cache, cache_len,
                            block_k=block_k,
                            interpret=resolve_interpret(interpret))
+
+
+# The device trace names the kernel's custom call after this wrapper, so
+# its name keeps the ``_decode_mha_jit`` prefix the roofline reader matches.
+@partial(jax.jit, static_argnames=("interpret",))
+def _decode_mha_jit_paged(q, k_pages, v_pages, tables, lengths, layer, *,
+                          interpret: bool):
+    return paged_decode_attention(q, k_pages, v_pages, tables, lengths, layer,
+                                  interpret=interpret)
+
+
+def decode_mha_paged(q, k_pages, v_pages, tables, lengths, layer, *,
+                     interpret: Optional[bool] = None):
+    """:func:`paged_decode_attention` under its jitted wrapper."""
+    return _decode_mha_jit_paged(q, k_pages, v_pages, tables, lengths, layer,
+                                 interpret=resolve_interpret(interpret))
 
 
 def decode_mha_cache_size() -> int:

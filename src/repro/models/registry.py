@@ -35,6 +35,14 @@ class ModelApi:
     # slot-resident state.  vmap-in_axes convention: traverse the result with
     # is_leaf=lambda x: x is None.
     cache_seq_axes: Callable[[PyTree], PyTree] = None
+    # Batched decode over the paged KV pool, one position per slot:
+    # ``(params, tokens [slots, 1], cache, tables [slots, W], write) ->
+    # (logits [slots, 1, V], cache)`` where ``cache`` holds the pool's
+    # buffers at the paged leaves and per-slot state elsewhere.  None where
+    # the family decodes gathered per-slot caches only.
+    decode_paged: Optional[Callable[..., Tuple[jnp.ndarray, PyTree]]] = None
+    # The decode-attention backend ``decode_paged`` calls.
+    attn_backend: Any = None
 
 
 def get_model(cfg: ModelConfig, attn_backend=None) -> ModelApi:
@@ -66,6 +74,10 @@ def get_model(cfg: ModelConfig, attn_backend=None) -> ModelApi:
             decode_step=lambda p, t, c, **kw: transformer.decode_step(
                 p, t, c, cfg, attn_backend=attn, **kw),
             cache_seq_axes=transformer.cache_seq_axes,
+            decode_paged=lambda p, t, c, tables, write:
+                transformer.decode_step_paged(p, t, c, tables, write, cfg,
+                                              attn_backend=attn),
+            attn_backend=attn,
         )
     if fam == "vlm":
         return ModelApi(
@@ -80,6 +92,10 @@ def get_model(cfg: ModelConfig, attn_backend=None) -> ModelApi:
             decode_step=lambda p, t, c, **kw: transformer.decode_step(
                 p, t, c, cfg, attn_backend=attn, **kw),
             cache_seq_axes=transformer.cache_seq_axes,
+            decode_paged=lambda p, t, c, tables, write:
+                transformer.decode_step_paged(p, t, c, tables, write, cfg,
+                                              attn_backend=attn),
+            attn_backend=attn,
         )
     if fam == "moe":
         return ModelApi(

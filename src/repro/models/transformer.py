@@ -237,6 +237,50 @@ def decode_step(
     return logits, new_cache
 
 
+def decode_step_paged(
+    params: PyTree, token: jnp.ndarray, cache: PyTree, tables: jnp.ndarray,
+    write, cfg: ModelConfig, *, attn_backend=None,
+) -> Tuple[jnp.ndarray, PyTree]:
+    """One decode step of ``slots`` requests over the paged KV pool, each at
+    its own position.  token [slots, 1] → logits [slots, 1, V].
+
+    ``cache``: ``{"k", "v"}`` the pool's buffers ``[L, 1, KV, num_blocks,
+    block_k, D]`` (``serving/kv_pool.py``), ``"length"`` int32 [slots], each
+    slot's position.  ``tables``: int32 [slots, W], each slot's pages.
+    ``write(leaf, layer, new [slots, 1, KV, D])`` puts each slot's new K or
+    V row at its position in its page and returns the leaf; each layer
+    writes before it attends, so attention sees the new token.  The
+    backend's ``decode_paged`` reads the pages through ``tables``.
+    """
+    attn = get_backend("attention", attn_backend)
+    x = L.embed_tokens(params["embed"], token)
+    pos = cache["length"]
+    positions = pos[:, None]
+
+    def body(carry, inp):
+        h, k_pages, v_pages = carry
+        block, layer = inp
+        hn = L.rms_norm(h, block["ln_attn"], cfg.norm_eps)
+        q, k, v = L.qkv_project(block["attn"], hn)
+        q = L.apply_rope(q, positions, cfg.rope_theta)
+        k = L.apply_rope(k, positions, cfg.rope_theta)
+        k_pages = write(k_pages, layer, k)
+        v_pages = write(v_pages, layer, v)
+        o = attn.decode_paged(q, k_pages, v_pages, tables, pos + 1, layer)
+        h = h + L.out_project(block["attn"], o.astype(h.dtype), h.dtype)
+        h = _mlp_apply(block, h, cfg)
+        return (h, k_pages, v_pages), None
+
+    n_layers = cache["k"].shape[0]
+    (x, ks, vs), _ = jax.lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (params["blocks"], jnp.arange(n_layers, dtype=jnp.int32)))
+    x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
+    table = params["embed"] if cfg.tie_embeddings else params["unembed"]
+    logits = L.unembed(x, table)
+    return logits, {**cache, "k": ks, "v": vs, "length": pos + 1}
+
+
 def param_count(cfg: ModelConfig) -> int:
     return cfg.param_count()
 

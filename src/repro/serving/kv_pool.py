@@ -12,10 +12,14 @@ the existing blocks.  This module turns that alignment into an allocator:
   decode step).
 * :class:`KVBlockPool` — the device side: one buffer per *growing* KV leaf
   of the family cache (``ModelApi.cache_seq_axes`` classifies leaves), laid
-  out ``[num_blocks, block_k, *rest, D]`` where the per-slot leaf is
-  ``[*rest, S, D]``.  ``gather`` rebuilds contiguous per-slot caches from
-  block tables inside the jitted decode step; ``scatter_token`` writes each
-  slot's newly decoded KV chunk back to its physical page.
+  out ``[*rest, num_blocks, block_k, D]`` where the per-slot leaf is
+  ``[*rest, S, D]``: the page axis sits where the sequence axis was, so a
+  ``(block_k, D)`` page of one layer and head is one tile-aligned block a
+  kernel can DMA, and admission is a reshape.  The paged decode step reads
+  pages through the block tables (``ModelApi.decode_paged``) and writes
+  each slot's new token with :meth:`KVBlockPool.write_token`; the other
+  families' step rebuilds contiguous per-slot caches with ``gather`` and
+  writes the new token back with ``scatter_token``.
 
 Two physical pages are reserved:
 
@@ -40,6 +44,7 @@ zero.  Freed-page reuse therefore needs no zeroing.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -142,7 +147,7 @@ class KVBlockPool:
     """Device-side paged storage for the growing KV leaves of one family.
 
     ``buffers`` mirrors the cache tree structure with ``None`` at
-    slot-resident leaves; each paged leaf is ``[num_blocks, block_k, *rest,
+    slot-resident leaves; each paged leaf is ``[*rest, num_blocks, block_k,
     D]`` for a per-slot leaf of shape ``[*rest, S, D]`` (seq axis -2).
     ``table_width`` fixes the block-table width (`S_slot = table_width *
     block_k` is the static capacity every gathered per-slot cache has), so
@@ -171,8 +176,8 @@ class KVBlockPool:
             s = leaf.shape[-2]
             layout.check_capacity(s)
             widths.add(s // bk)
-            rest = leaf.shape[:-2] + leaf.shape[-1:]
-            return jnp.zeros((num_blocks, bk) + rest, leaf.dtype)
+            return jnp.zeros(leaf.shape[:-2] + (num_blocks, bk)
+                             + leaf.shape[-1:], leaf.dtype)
 
         buffers = jax.tree_util.tree_map(mk, seq_axes, slot_cache_template,
                                          is_leaf=_is_none)
@@ -200,19 +205,8 @@ class KVBlockPool:
             raise ValueError(
                 f"request needs {n} pages but tables hold {self.table_width}")
         ids = self.allocator.alloc(n)
-        bk = max(1, int(self.layout.block_k))
-        idx = jnp.asarray(ids, jnp.int32)
-
-        def write(ax, buf, leaf):
-            if ax is None:
-                return buf
-            # [*rest, S, D] → per-page chunks [n, bk, *rest, D]
-            x = jnp.moveaxis(leaf, -2, 0)[: n * bk]
-            x = x.reshape((n, bk) + x.shape[1:])
-            return buf.at[idx].set(x.astype(buf.dtype))
-
-        self.buffers = jax.tree_util.tree_map(
-            write, self.seq_axes, self.buffers, cache, is_leaf=_is_none)
+        self.buffers = _write_pages(self.buffers, cache,
+                                    jnp.asarray(ids, jnp.int32))
         table = np.full((self.table_width,), NULL_BLOCK, np.int32)
         table[:n] = ids
         return table
@@ -222,7 +216,7 @@ class KVBlockPool:
         entries; the rest are null padding)."""
         self.allocator.free([int(b) for b in table[:n_blocks]])
 
-    # -- jit-side gather / scatter ----------------------------------------
+    # -- jit-side reads and writes ----------------------------------------
 
     def gather(self, buffers: PyTree, tables: jnp.ndarray) -> PyTree:
         """Rebuild contiguous per-slot caches from block tables.
@@ -235,41 +229,89 @@ class KVBlockPool:
         def g(ax, buf):
             if ax is None:
                 return None
-            x = buf[tables]                      # [slots, W, bk, *rest, D]
-            s = x.shape[0]
-            x = x.reshape((s, x.shape[1] * x.shape[2]) + x.shape[3:])
-            return jnp.moveaxis(x, 1, -2)        # [slots, *rest, S, D]
+            x = jnp.take(buf, tables, axis=-3)   # [*rest, slots, W, bk, D]
+            x = x.reshape(x.shape[:-4] + (x.shape[-4], -1, x.shape[-1]))
+            return jnp.moveaxis(x, -3, 0)        # [slots, *rest, S, D]
 
         return jax.tree_util.tree_map(g, self.seq_axes, buffers,
                                       is_leaf=_is_none)
 
-    def scatter_token(self, buffers: PyTree, chunks: PyTree,
-                      tables: jnp.ndarray, positions: jnp.ndarray,
-                      active: jnp.ndarray) -> PyTree:
-        """Write each slot's newly decoded KV chunk to its physical page.
-
-        ``chunks``: paged tree with per-slot leaves ``[slots, *rest, D]``
-        (the decode step's write at ``positions[slot]``, already extracted
-        from the gathered cache).  Inactive slots are redirected to the sink
-        page so they can never touch a re-allocated one.  Two active slots
-        never collide (they own disjoint pages); sink collisions are
-        harmless because the sink is never read.
-        """
-        if self.table_width == 0:
-            return buffers
+    def token_index(self, tables: jnp.ndarray, positions: jnp.ndarray,
+                    active: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """The physical page and the offset in it where each slot's token
+        at ``positions[slot]`` goes.  Inactive slots are redirected to the
+        sink page so they can never touch a re-allocated one.  Two active
+        slots never collide (they own disjoint pages); sink collisions are
+        harmless because the sink is never read."""
         bk = max(1, int(self.layout.block_k))
-        slot_ix = jnp.arange(tables.shape[0])
         # Clip so a long-vacant slot's (discarded) position can't index past
         # the table; active positions are < capacity by allocation.
         block_ix = jnp.clip(positions // bk, 0, tables.shape[1] - 1)
-        page = tables[slot_ix, block_ix]
-        page = jnp.where(active, page, SINK_BLOCK)
-        off = positions % bk
+        page = tables[jnp.arange(tables.shape[0]), block_ix]
+        return jnp.where(active, page, SINK_BLOCK), positions % bk
+
+    @staticmethod
+    def write_token(leaf: jnp.ndarray, layer, page: jnp.ndarray,
+                    off: jnp.ndarray, new: jnp.ndarray) -> jnp.ndarray:
+        """Write each slot's new row ``new [slots, *rest', D]`` into layer
+        ``layer`` of a pool leaf ``[L, *rest', num_blocks, block_k, D]`` at
+        ``(page, off)`` from :meth:`token_index`: the paged decode step's
+        one write per layer, in place on the donated pool."""
+        with jax.named_scope("serve.pool_scatter"):
+            return _put_tokens(leaf, page, off, new, lead=(layer,))
+
+    def scatter_token(self, buffers: PyTree, chunks: PyTree,
+                      tables: jnp.ndarray, positions: jnp.ndarray,
+                      active: jnp.ndarray) -> PyTree:
+        """Write each slot's newly decoded KV chunk to its physical page
+        (:meth:`token_index`).
+
+        ``chunks``: paged tree with per-slot leaves ``[slots, *rest, D]``
+        (the gathered decode step's write at ``positions[slot]``, already
+        extracted from the gathered cache).
+        """
+        if self.table_width == 0:
+            return buffers
+        page, off = self.token_index(tables, positions, active)
 
         def s(ax, buf, chunk):
             if ax is None:
                 return buf
-            return buf.at[page, off].set(chunk.astype(buf.dtype))
+            return _put_tokens(buf, page, off, chunk)
 
         return jax.tree_util.tree_map(s, self.seq_axes, buffers, chunks,
                                       is_leaf=_is_none)
+
+
+def _put_tokens(buf: jnp.ndarray, page: jnp.ndarray, off: jnp.ndarray,
+                rows: jnp.ndarray, lead: Tuple = ()) -> jnp.ndarray:
+    """Write ``rows[slot]`` (``[*rest, D]``) at ``(page[slot], off[slot])``
+    of a pool leaf ``[*lead dims, *rest, num_blocks, block_k, D]``, below
+    the indices ``lead``.  One ``dynamic_update_slice`` a slot: it updates
+    the buffer in place in whatever layout the buffer has, where a scatter
+    over the page and offset axes has the compiler relayout the whole
+    leaf around it."""
+    rest = buf.shape[len(lead):-3]
+    for slot in range(rows.shape[0]):
+        row = rows[slot].reshape((1,) * len(lead) + rest + (1, 1, -1))
+        start = tuple(lead) + (0,) * len(rest) + (page[slot], off[slot], 0)
+        buf = jax.lax.dynamic_update_slice(buf, row.astype(buf.dtype), start)
+    return buf
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _write_pages(buffers: PyTree, cache: PyTree, ids: jnp.ndarray) -> PyTree:
+    """Copy the first ``len(ids)`` pages' worth of each paged leaf of a
+    prefilled per-slot cache (``[*rest, S, D]``) into pages ``ids`` of the
+    pool, in place on the donated buffers."""
+    n = ids.shape[0]
+
+    def write(buf, leaf):
+        if buf is None:
+            return None
+        bk = buf.shape[-2]
+        x = leaf[..., : n * bk, :]
+        x = x.reshape(x.shape[:-2] + (n, bk, x.shape[-1]))
+        return buf.at[..., ids, :, :].set(x.astype(buf.dtype))
+
+    return jax.tree_util.tree_map(write, buffers, cache, is_leaf=_is_none)

@@ -9,10 +9,15 @@ the paper's §V-B buffering made per-request):
 * a fixed-slot decode batch (``num_slots``): one jitted, donated decode step
   whose shapes never change, so admitting or retiring a request is a pure
   array update — **zero retraces** (gated by the retrace-counter test);
-* per-slot caches rebuilt each step from the :class:`KVBlockPool` via block
-  tables, so a request's pages are scattered physically but contiguous
-  logically (defrag-free reuse);
-* per-slot ``length`` — the vmap over slots turns every family's scalar
+* KV in the :class:`KVBlockPool`, reached through block tables, so a
+  request's pages are scattered physically but contiguous logically
+  (defrag-free reuse).  Where the model offers a paged decode
+  (``ModelApi.decode_paged``: the dense and vlm families) and the step is
+  not sequence-sharded, attention reads the pages in place and the step
+  writes only each slot's new token; every other step gathers each slot's
+  cache and scatters the new token back;
+* per-slot ``length`` — the paged decode takes one position per slot, and
+  on the gather path the vmap over slots turns every family's scalar
   ``length`` into one length per request *without touching family decode
   signatures*, which is what closes the shared-``cache_len`` gap;
 * requests admitted mid-decode as slots free up, retired the step their
@@ -20,9 +25,10 @@ the paper's §V-B buffering made per-request):
 
 Bitwise contract: each request's tokens and final-step logits are bitwise
 equal (fp32 cache math) to the same request served alone through the static
-``generate`` oracle at equal cache capacity — vmap-of-B=1 decode is
-bit-identical to solo B=1 decode on XLA, and masked positions contribute
-exactly +0.0 regardless of stale pool-page contents (see ``kv_pool.py``).
+``generate`` oracle at equal cache capacity — the batched step is
+bit-identical to solo B=1 decode on XLA, masked positions contribute
+exactly +0.0 regardless of stale pool-page contents (see ``kv_pool.py``),
+and the pages a paged read skips are exactly those masked positions.
 ``tests/test_continuous_batching.py`` holds this across backends × families
 × arrival orders.
 
@@ -97,7 +103,8 @@ class RequestScheduler:
     holding a maximal request) plus the two reserved pages.
 
     ``mesh``/``axis_name`` switch the decode step to the sequence-sharded
-    variant (shard_map over the paged leaves' S axis).
+    variant (shard_map over the gathered leaves' S axis), which keeps the
+    gather: its shards split each slot's contiguous cache.
     """
 
     def __init__(self, model, params: PyTree, prefill_fn: Callable,
@@ -145,18 +152,29 @@ class RequestScheduler:
         self._tables_dev = jnp.asarray(self._tables)
         self._active_dev = jnp.asarray(self._active)
         self._slots: List[Optional[_Slot]] = [None] * self.num_slots
+        self.paged = _takes_paged_step(model, mesh)
         self._step_fn = self._build_step()
+        # Attention reads up to each slot's last valid page, or the whole
+        # table where the backend gathers it.
+        self._reads_valid_pages = self.paged and getattr(
+            model.attn_backend, "skips_invalid_pages", False)
         self.steps_run = 0          # decode steps executed (bench: utilization)
         self.tokens_emitted = 0
+        self.paged_steps = 0        # of them, served through block tables
         # Per decode step, summed: the cache positions the active slots
-        # attend over, and the num_slots x slot_capacity positions
-        # ``pool.gather`` materializes.  Their ratio is the share of the
-        # gathered cache that holds valid positions; each step's pair also
-        # rides on its ``serve.step`` span (``valid=``, ``capacity=``).
+        # attend over, the num_slots x slot_capacity positions the slots'
+        # tables span, and the positions in the pages attention fetched.
+        # Each step's three also ride on its ``serve.step`` span
+        # (``valid=``, ``capacity=``, ``read=``).
         self.decode_positions = 0
         self.capacity_positions = 0
+        self.read_positions = 0
 
     # ------------------------------------------------------------------ #
+
+    def _build_step(self):
+        return build_step(self.model, self.pool, self.seq_axes,
+                          mesh=self.mesh, axis_name=self.axis_name)
 
     def _template_cache(self) -> PyTree:
         batch = {"tokens": jnp.zeros((1, 1), jnp.int32)}
@@ -173,79 +191,6 @@ class RequestScheduler:
             return {"frames": jnp.zeros(
                 (1, cfg.frontend_tokens, cfg.d_model), jnp.bfloat16)}
         return {}
-
-    def _build_step(self):
-        model, pool, seq_axes = self.model, self.pool, self.seq_axes
-        mesh, axis = self.mesh, self.axis_name
-
-        def chunks_at(paged: PyTree, positions: jnp.ndarray) -> PyTree:
-            """Per-slot KV written this step: slice seq position p from each
-            paged leaf ([slots, *rest, S, D] → [slots, *rest, D])."""
-            def one(ax, leaf):
-                if ax is None:
-                    return None
-                def slot_slice(x, p):
-                    sl = jax.lax.dynamic_slice_in_dim(x, p, 1, axis=-2)
-                    return jnp.squeeze(sl, axis=-2)
-                return jax.vmap(slot_slice)(leaf, positions)
-            return jax.tree_util.tree_map(one, seq_axes, paged,
-                                          is_leaf=lambda x: x is None)
-
-        def step(params, tokens, resident, buffers, tables, active):
-            positions = resident["length"]                    # [slots]
-            with jax.named_scope("serve.pool_gather"):
-                paged = pool.gather(buffers, tables)
-
-            def per_slot(tok, res, pg, **kw):
-                cache = merge_cache(pg, res, seq_axes)
-                logits, new_cache = model.decode_step(params, tok, cache,
-                                                      **kw)
-                new_pg, new_res = split_cache(new_cache, seq_axes)
-                return logits, new_res, new_pg
-
-            if mesh is None:
-                with jax.named_scope("serve.decode"):
-                    logits, new_res, new_paged = jax.vmap(per_slot)(
-                        tokens, resident, paged)
-            else:
-                from jax.sharding import PartitionSpec as P
-
-                def pspec(ax, leaf):
-                    if ax is None:
-                        return P()
-                    nd = leaf.ndim                # [slots, *rest, S, D]
-                    return P(*([None] * (nd - 2)), axis, None)
-
-                paged_specs = jax.tree_util.tree_map(
-                    pspec, seq_axes, paged, is_leaf=lambda x: x is None)
-                res_specs = jax.tree_util.tree_map(lambda _: P(), resident)
-
-                body = jax.shard_map(
-                    lambda p, t, r, g: jax.vmap(
-                        lambda tok, res, pg: per_slot(
-                            tok, res, pg, seq_shard_axes=axis))(t, r, g),
-                    mesh=mesh,
-                    in_specs=(P(), P(), res_specs, paged_specs),
-                    out_specs=(P(), res_specs, paged_specs),
-                    check_vma=False,
-                )
-                with jax.named_scope("serve.decode"):
-                    logits, new_res, new_paged = body(params, tokens,
-                                                      resident, paged)
-
-            with jax.named_scope("serve.pool_scatter"):
-                chunks = chunks_at(new_paged, positions)
-                buffers = pool.scatter_token(buffers, chunks,
-                                             tables, positions, active)
-            # logits: [slots, 1, 1, V].  The greedy argmax matches the static
-            # path's per-request `argmax(logits[:, -1:], -1)` elementwise.
-            with jax.named_scope("serve.sample"):
-                next_tok = jnp.argmax(logits[..., -1:, :], axis=-1) \
-                    .astype(jnp.int32)                   # [slots, 1, 1]
-            return logits[:, 0, -1], next_tok, new_res, buffers
-
-        # Donate the big rotating state: slot-resident stacks + pool pages.
-        return jax.jit(step, donate_argnums=(2, 3))
 
     # ------------------------------------------------------------------ #
     # host-side admission / retirement
@@ -312,6 +257,17 @@ class RequestScheduler:
         self._resident = {**self._resident,
                           "length": self._resident["length"].at[slot].set(0)}
 
+    def _read_positions(self, lengths: Sequence[int]) -> int:
+        """Cache positions in the pages one step's attention fetches: each
+        active slot's pages up to its last valid one and a vacant slot's
+        first page, or every slot's whole table."""
+        if not self._reads_valid_pages:
+            return self.num_slots * self.slot_capacity
+        bk = self.layout.block_k
+        vacant = self.num_slots - len(lengths)
+        return bk * (vacant + sum(self.layout.blocks_for(n)
+                                  for n in lengths))
+
     # ------------------------------------------------------------------ #
 
     @spanned("serve.run")
@@ -347,19 +303,23 @@ class RequestScheduler:
                 continue
             with span("serve.token_wait"):
                 input_tokens = np.asarray(self._tokens)[:, 0, 0]
-            valid = sum(st.prefix + len(st.tokens) + 1
-                        for st in self._slots if st is not None)
+            lengths = [st.prefix + len(st.tokens) + 1
+                       for st in self._slots if st is not None]
+            valid = sum(lengths)
             capacity = self.num_slots * self.slot_capacity
+            read = self._read_positions(lengths)
             self.decode_positions += valid
             self.capacity_positions += capacity
+            self.read_positions += read
             with span("serve.step", step=self.steps_run, valid=valid,
-                      capacity=capacity):
+                      capacity=capacity, read=read):
                 logits, next_tok, self._resident, self.pool.buffers = \
                     self._step_fn(self.params, self._tokens, self._resident,
                                   self.pool.buffers, self._tables_dev,
                                   self._active_dev)
             self._tokens = next_tok
             self.steps_run += 1
+            self.paged_steps += self.paged
             logits_np = None
             for slot in range(self.num_slots):
                 st = self._slots[slot]
@@ -375,3 +335,109 @@ class RequestScheduler:
                                      results)
             step_idx += 1
         return results
+
+
+def _takes_paged_step(model, mesh) -> bool:
+    return model.decode_paged is not None and mesh is None
+
+
+def build_step(model, pool: KVBlockPool, seq_axes: PyTree, mesh=None,
+               axis_name: str = "seq") -> Callable:
+    """The scheduler's jitted decode step over every slot, ``(params,
+    tokens [slots, 1, 1], resident, buffers, tables, active) -> (logits
+    [slots, V], next tokens [slots, 1, 1], resident, buffers)``, with the
+    slot-resident state and the pool's buffers donated.
+
+    Where ``model.decode_paged`` exists and ``mesh`` is None, attention
+    reads the pool's pages through ``tables`` and the step writes only each
+    slot's new token (:meth:`KVBlockPool.write_token`).  Otherwise it
+    gathers every slot's cache, runs the family's ``decode_step`` vmapped
+    over slots (in ``shard_map`` over the gathered S axis on a mesh), and
+    scatters each slot's new token back.
+    """
+
+    def sample(logits):
+        # logits [slots, 1, V].  The greedy argmax matches the static
+        # path's per-request `argmax(logits[:, -1:], -1)` elementwise.
+        with jax.named_scope("serve.sample"):
+            return jnp.argmax(logits[:, None, -1:, :], axis=-1) \
+                .astype(jnp.int32)                       # [slots, 1, 1]
+
+    def paged_step(params, tokens, resident, buffers, tables, active):
+        page, off = pool.token_index(tables, resident["length"], active)
+
+        def write(leaf, layer, new):
+            return pool.write_token(leaf, layer, page, off, new)
+
+        with jax.named_scope("serve.decode"):
+            logits, cache = model.decode_paged(
+                params, tokens[:, 0], merge_cache(buffers, resident, seq_axes),
+                tables, write)
+        buffers, resident = split_cache(cache, seq_axes)
+        return logits[:, -1], sample(logits), resident, buffers
+
+    def chunks_at(paged: PyTree, positions: jnp.ndarray) -> PyTree:
+        """Per-slot KV written this step: slice seq position p from each
+        paged leaf ([slots, *rest, S, D] → [slots, *rest, D])."""
+        def one(ax, leaf):
+            if ax is None:
+                return None
+            def slot_slice(x, p):
+                sl = jax.lax.dynamic_slice_in_dim(x, p, 1, axis=-2)
+                return jnp.squeeze(sl, axis=-2)
+            return jax.vmap(slot_slice)(leaf, positions)
+        return jax.tree_util.tree_map(one, seq_axes, paged,
+                                      is_leaf=lambda x: x is None)
+
+    def gather_step(params, tokens, resident, buffers, tables, active):
+        positions = resident["length"]                    # [slots]
+        with jax.named_scope("serve.pool_gather"):
+            paged = pool.gather(buffers, tables)
+
+        def per_slot(tok, res, pg, **kw):
+            cache = merge_cache(pg, res, seq_axes)
+            logits, new_cache = model.decode_step(params, tok, cache, **kw)
+            new_pg, new_res = split_cache(new_cache, seq_axes)
+            return logits, new_res, new_pg
+
+        if mesh is None:
+            with jax.named_scope("serve.decode"):
+                logits, new_res, new_paged = jax.vmap(per_slot)(
+                    tokens, resident, paged)
+        else:
+            from jax.sharding import PartitionSpec as P
+
+            def pspec(ax, leaf):
+                if ax is None:
+                    return P()
+                nd = leaf.ndim                # [slots, *rest, S, D]
+                return P(*([None] * (nd - 2)), axis_name, None)
+
+            paged_specs = jax.tree_util.tree_map(
+                pspec, seq_axes, paged, is_leaf=lambda x: x is None)
+            res_specs = jax.tree_util.tree_map(lambda _: P(), resident)
+
+            body = jax.shard_map(
+                lambda p, t, r, g: jax.vmap(
+                    lambda tok, res, pg: per_slot(
+                        tok, res, pg, seq_shard_axes=axis_name))(t, r, g),
+                mesh=mesh,
+                in_specs=(P(), P(), res_specs, paged_specs),
+                out_specs=(P(), res_specs, paged_specs),
+                check_vma=False,
+            )
+            with jax.named_scope("serve.decode"):
+                logits, new_res, new_paged = body(params, tokens,
+                                                  resident, paged)
+
+        with jax.named_scope("serve.pool_scatter"):
+            chunks = chunks_at(new_paged, positions)
+            buffers = pool.scatter_token(buffers, chunks,
+                                         tables, positions, active)
+        logits = logits[:, 0]                            # [slots, 1, V]
+        return logits[:, -1], sample(logits), new_res, buffers
+
+    # Donate the big rotating state: slot-resident stacks + pool pages.
+    step = paged_step if _takes_paged_step(model, mesh) else gather_step
+    return jax.jit(step,
+                   donate_argnums=(2, 3))

@@ -186,6 +186,83 @@ class TestDifferentialParity:
             assert np.array_equal(res.final_logits, ref_logits)
 
 
+def _dense_stream(cfg, n=5, seed=11):
+    rng = np.random.default_rng(seed)
+    return [Request(rid=i,
+                    prompt=rng.integers(0, cfg.vocab_size,
+                                        (int(rng.integers(2, 8)),))
+                    .astype(np.int32),
+                    max_new_tokens=int(rng.integers(1, 6)),
+                    arrival=int(rng.integers(0, 3)))
+            for i in range(n)]
+
+
+def _scheduler(eng, reqs, model=None, **kw):
+    cap = _stream_capacity(eng, reqs)
+    return RequestScheduler(model or eng.model, eng.params, eng._prefill,
+                            num_slots=NUM_SLOTS, slot_capacity=cap,
+                            layout=eng.cache_layout(cap), **kw)
+
+
+class TestPagedStep:
+    """Which families decode through block tables, and that doing so
+    changes no bit of what the gathering step serves."""
+
+    @pytest.mark.parametrize("backend", BACKENDS["transformer"])
+    def test_dense_decodes_every_step_paged(self, backend):
+        cfg = _family_cfg("transformer")
+        eng = ServingEngine(cfg, attn_backend=_backend(backend))
+        reqs = _dense_stream(cfg)
+        sched = _scheduler(eng, reqs)
+        sched.run(reqs)
+        assert sched.paged and sched.steps_run > 0
+        assert sched.paged_steps == sched.steps_run
+        # the kernel fetches pages up to each slot's last valid one; the
+        # jnp backends gather the whole table
+        assert sched.decode_positions <= sched.read_positions \
+            <= sched.capacity_positions
+        if backend == "pallas-splitk":
+            assert sched.read_positions < sched.capacity_positions
+        else:
+            assert sched.read_positions == sched.capacity_positions
+
+    @pytest.mark.parametrize("backend", BACKENDS["transformer"])
+    def test_paged_step_matches_gather_step_bitwise(self, backend):
+        cfg = _family_cfg("transformer")
+        eng = ServingEngine(cfg, attn_backend=_backend(backend))
+        reqs = _dense_stream(cfg, n=6, seed=5)
+        paged = {r.rid: r for r in _scheduler(eng, reqs).run(reqs)}
+        gather = _scheduler(eng, reqs, model=dataclasses.replace(
+            eng.model, decode_paged=None))
+        assert not gather.paged
+        for r in gather.run(reqs):
+            np.testing.assert_array_equal(r.tokens, paged[r.rid].tokens)
+            assert np.array_equal(r.final_logits, paged[r.rid].final_logits)
+
+    @pytest.mark.parametrize("family", ["moe", "encdec"])
+    def test_other_families_gather(self, family):
+        cfg = _family_cfg(family)
+        eng = ServingEngine(cfg, seed=1 if family == "moe" else 0)
+        reqs = _mk_requests(cfg, np.random.default_rng(7), 2,
+                            arrivals=[0, 0])
+        sched = _scheduler(eng, reqs)
+        sched.run(reqs)
+        assert not sched.paged and sched.steps_run > 0
+        assert sched.paged_steps == 0
+        assert sched.read_positions == sched.capacity_positions
+
+    def test_sequence_sharded_step_gathers(self):
+        from repro.launch.mesh import make_mesh
+
+        cfg = _family_cfg("transformer")
+        eng = ServingEngine(cfg)
+        reqs = _dense_stream(cfg, n=3)
+        sched = _scheduler(eng, reqs, mesh=make_mesh((1,), ("seq",)))
+        sched.run(reqs)
+        assert not sched.paged and sched.steps_run > 0
+        assert sched.paged_steps == 0
+
+
 class TestSchedulerEfficiency:
     def test_ragged_stream_beats_padded_static_batching(self):
         """The quantity the ``serving_cb_*`` bench rows gate, asserted
@@ -296,15 +373,17 @@ class TestBlockAllocatorProperties:
         owned = np.asarray(table[:4], np.int32)
         # a retired slot (active=False) writing at any position must only
         # touch the sink page
-        before = np.asarray(pool.buffers["k"][owned])
+        before = np.asarray(pool.buffers["k"][..., owned, :, :])
         chunks = {"k": jnp.full((1, 1, 1, 2, 3), -7.0),
                   "v": jnp.full((1, 1, 1, 2, 3), -7.0), "length": None}
         tables = jnp.asarray(np.stack([table]), jnp.int32)
         new = pool.scatter_token(pool.buffers, chunks, tables,
                                  jnp.asarray([5], jnp.int32),
                                  jnp.asarray([False]))
-        np.testing.assert_array_equal(np.asarray(new["k"][owned]), before)
-        assert np.all(np.asarray(new["k"][SINK_BLOCK, 1]) == -7.0)
+        # pool leaves are [L, B, KV, num_blocks, block_k, D]
+        np.testing.assert_array_equal(
+            np.asarray(new["k"][..., owned, :, :]), before)
+        assert np.all(np.asarray(new["k"][..., SINK_BLOCK, 1, :]) == -7.0)
 
 
 class TestBlockTableRoundTrip:
@@ -314,9 +393,10 @@ class TestBlockTableRoundTrip:
            n_blocks_req=st.integers(min_value=1, max_value=6))
     def test_admit_gather_is_exact(self, seed, block_k, n_blocks_req):
         """block-table → flat-cache round trip: admit a random cache into
-        randomly interleaved physical pages, gather through the table, and
-        get the original buffer back bit-for-bit (beyond the request's own
-        pages the gather reads the zero null page)."""
+        randomly interleaved physical pages, read each page back where the
+        table puts it and gather through the table, and get the original
+        buffer back bit-for-bit (beyond the request's own pages the gather
+        reads the zero null page)."""
         rng = np.random.default_rng(seed)
         layout = KVCacheLayout(block_k=block_k)
         width = 6
@@ -338,8 +418,16 @@ class TestBlockTableRoundTrip:
                  "v": jnp.asarray(rng.standard_normal(shape), jnp.float32),
                  "length": None}
         table = pool.admit(cache, n_blocks_req * block_k)
-        got = pool.gather(pool.buffers, jnp.asarray(table[None], jnp.int32))
         valid = n_blocks_req * block_k
+        # each page holds its block_k positions where the table says:
+        # [L, B, KV, num_blocks, block_k, D]
+        for j in range(n_blocks_req):
+            page = slice(j * block_k, (j + 1) * block_k)
+            for leaf in ("k", "v"):
+                np.testing.assert_array_equal(
+                    np.asarray(pool.buffers[leaf][..., table[j], :, :]),
+                    np.asarray(cache[leaf][..., page, :]))
+        got = pool.gather(pool.buffers, jnp.asarray(table[None], jnp.int32))
         for leaf in ("k", "v"):
             np.testing.assert_array_equal(
                 np.asarray(got[leaf][0, ..., :valid, :]),

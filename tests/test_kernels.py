@@ -12,7 +12,8 @@ import numpy as np
 from repro.core.sparse import bsr_from_dense, random_sparse
 from repro.kernels.bsr_spmm.ops import prepare_bsr_operands, bsr_spmm
 from repro.kernels.bsr_spmm.ref import bsr_spmm_fused_ref
-from repro.kernels.decode_attention.ops import decode_mha
+from repro.kernels.decode_attention.decode_attention import decode_attention
+from repro.kernels.decode_attention.ops import decode_mha, decode_mha_paged
 from repro.kernels.decode_attention.ref import decode_attention_ref
 from repro.kernels.flash_attention.ops import mha
 from repro.kernels.flash_attention.ref import flash_attention_ref
@@ -203,6 +204,97 @@ class TestDecodeAttention:
         combined = (np.asarray(o1) * w1[..., None] + np.asarray(o2) * w2[..., None]) / (
             (w1 + w2)[..., None])
         np.testing.assert_allclose(combined, full_o, rtol=1e-5, atol=1e-5)
+
+
+# The paged pool as the scheduler keeps it: [L, 1, KV, num_blocks, bk, D],
+# page 0 the zero null page.
+PAGED_L, PAGED_KV, PAGED_G, PAGED_D, PAGED_BK, PAGED_W = 2, 2, 2, 32, 8, 4
+PAGED_NB = 2 + 4 * PAGED_W
+
+
+def _paged_case(rng, dtype, lengths):
+    """A pool, the slots' queries and tables: each slot's valid pages drawn
+    from a shuffled free list (scrambled physical order), the rest of its
+    table the null page."""
+    shape = (PAGED_L, 1, PAGED_KV, PAGED_NB, PAGED_BK, PAGED_D)
+    kp = rng.standard_normal(shape).astype(np.float32)
+    vp = rng.standard_normal(shape).astype(np.float32)
+    kp[:, :, :, 0] = vp[:, :, :, 0] = 0.0
+    free = list(rng.permutation(np.arange(2, PAGED_NB)))
+    tables = np.zeros((len(lengths), PAGED_W), np.int32)
+    for s, n in enumerate(lengths):
+        for j in range(-(-n // PAGED_BK)):
+            tables[s, j] = free.pop()
+    q = rng.standard_normal((len(lengths), PAGED_KV * PAGED_G, PAGED_D))
+    return (jnp.asarray(q, dtype), jnp.asarray(kp, dtype),
+            jnp.asarray(vp, dtype), tables)
+
+
+def _gathered(pages, layer, table):
+    """One slot's contiguous [1, KV, W * bk, D] cache of one layer."""
+    x = pages[layer, 0][:, table]                    # [KV, W, bk, D]
+    return x.reshape(1, PAGED_KV, PAGED_W * PAGED_BK, PAGED_D)
+
+
+def _assert_paged_matches_gathered(q, kp, vp, tables, lengths, layer):
+    out, lse = decode_mha_paged(q, kp, vp, jnp.asarray(tables),
+                                jnp.asarray(lengths, jnp.int32), layer,
+                                interpret=True)
+    for s, n in enumerate(lengths):
+        want_o, want_lse = decode_attention(
+            q[s:s + 1], _gathered(kp, layer, tables[s]),
+            _gathered(vp, layer, tables[s]), n, block_k=PAGED_BK,
+            interpret=True)
+        np.testing.assert_array_equal(np.asarray(out[s:s + 1]),
+                                      np.asarray(want_o), err_msg=f"slot {s}")
+        np.testing.assert_array_equal(np.asarray(lse[s:s + 1]),
+                                      np.asarray(want_lse),
+                                      err_msg=f"slot {s}")
+
+
+class TestPagedDecodeAttention:
+    """The paged split-K kernel against ``decode_attention`` on the cache
+    the block tables describe, gathered: bitwise equal, since it runs the
+    same blocks and skips only wholly masked ones."""
+
+    BK, CAP = PAGED_BK, PAGED_W * PAGED_BK
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("lengths", [
+        (1,), (BK - 1,), (BK,), (BK + 1,), (CAP,),
+        (1, BK + 1, CAP, BK - 1),       # 4 slots of different lengths
+    ], ids=["1", "bk-1", "bk", "bk+1", "full", "4-slots"])
+    @pytest.mark.parametrize("layer", [0, PAGED_L - 1])
+    def test_bitwise_equal_to_gathered(self, dtype, lengths, layer):
+        rng = np.random.default_rng(len(lengths) * 100 + lengths[0])
+        q, kp, vp, tables = _paged_case(rng, dtype, lengths)
+        _assert_paged_matches_gathered(q, kp, vp, tables, lengths, layer)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("offset", [0, PAGED_BK - 1])
+    def test_new_token_written_into_its_page(self, dtype, offset):
+        """The decode step's order: each slot's new token is written into
+        its page (``KVBlockPool.write_token``), then attended over; the same
+        as writing it into the gathered cache."""
+        from repro.serving.kv_pool import KVBlockPool
+
+        pos = np.array([PAGED_BK + offset, offset, 2 * PAGED_BK + offset,
+                        3 * PAGED_BK + offset], np.int32)
+        lengths = tuple(int(p) + 1 for p in pos)
+        rng = np.random.default_rng(offset)
+        q, kp, vp, tables = _paged_case(rng, dtype, lengths)
+        layer = 1
+        new = jnp.asarray(rng.standard_normal(
+            (len(pos), 1, PAGED_KV, PAGED_D)), dtype)
+        page = jnp.asarray(tables[np.arange(len(pos)), pos // PAGED_BK])
+        off = jnp.asarray(pos % PAGED_BK)
+        kp2 = KVBlockPool.write_token(kp, layer, page, off, new)
+        vp2 = KVBlockPool.write_token(vp, layer, page, off, -new)
+        for s, p in enumerate(pos):
+            got = _gathered(kp2, layer, tables[s])[0, :, p]
+            np.testing.assert_array_equal(np.asarray(got),
+                                          np.asarray(new[s, 0]))
+        _assert_paged_matches_gathered(q, kp2, vp2, tables, lengths, layer)
 
 
 class TestSsdScan:

@@ -139,3 +139,67 @@ def test_internlm2_slot_decode_step(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("cap,block_k", BUCKETS)
+def test_paged_decode_attention_bucket(one_chip, cap, block_k):
+    """The paged kernel as the decode step calls it: 4 slots over a pool of
+    internlm2-1.8b's 24 layers, a page one block of each bucket."""
+    from repro.kernels.decode_attention.ops import decode_mha_paged
+
+    slots, L, bf16 = 4, 24, jnp.bfloat16
+    width = cap // block_k
+    pool = (L, 1, KV, 2 + slots * width, block_k, D)
+    compiled = _compile(
+        one_chip, partial(decode_mha_paged, interpret=False),
+        ((slots, H, D), bf16), (pool, bf16), (pool, bf16),
+        ((slots, width), jnp.int32), ((slots,), jnp.int32), ((), jnp.int32))
+    # the roofline reader finds the kernel by its wrapper's name
+    assert "_decode_mha_jit_paged" in compiled.as_text()
+
+
+def test_internlm2_paged_decode_step(one_chip):
+    """The scheduler's paged decode step at ``lm_code``'s shapes: 4 slots,
+    capacity 3328 in pages of 256, 54 pages, compiled ``pallas-splitk``.
+    Its scratch stays below one gathered cache of every slot, and below
+    one leaf of the pool (a scatter over the page axes had the compiler
+    relayout a whole leaf around it), so no copy of the pool or of a
+    per-slot cache hides in it."""
+    from repro.configs import get_config
+    from repro.core.backends import PallasSplitKAttention
+    from repro.models.registry import get_model
+    from repro.serving.kv_pool import BlockAllocator, KVBlockPool
+    from repro.serving.scheduler import build_step
+
+    cfg = get_config("internlm2-1.8b")
+    attn = PallasSplitKAttention(interpret=False)
+    model = get_model(cfg, attn_backend=attn)
+    slots, cap, num_blocks = 4, 3328, 54
+    layout = attn.cache_layout(cap)
+    assert layout.block_k == 256
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    template = jax.eval_shape(
+        lambda p: model.prefill(p, {"tokens": jnp.zeros((1, 1), jnp.int32)},
+                                cap)[1], params)
+    axes = model.cache_seq_axes(template)
+    buffers = jax.eval_shape(
+        lambda t: KVBlockPool.build(t, axes, layout, num_blocks).buffers,
+        template)
+    pool = KVBlockPool(layout=layout, num_blocks=num_blocks,
+                       table_width=cap // layout.block_k, seq_axes=axes,
+                       buffers=None, allocator=BlockAllocator(num_blocks))
+    place = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    resident = {"k": None, "v": None, "length": sds((slots,), jnp.int32)}
+    compiled = build_step(model, pool, axes).lower(
+        place(params), sds((slots, 1, 1), jnp.int32), resident,
+        place(buffers), sds((slots, pool.table_width), jnp.int32),
+        sds((slots,), jnp.bool_)).compile()
+    assert "_decode_mha_jit_paged" in compiled.as_text()
+    gathered = (slots * 2 * cfg.n_layers * cfg.n_kv_heads * cap * cfg.d_head
+                * 2)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < gathered
+    assert temp < buffers["k"].size * buffers["k"].dtype.itemsize

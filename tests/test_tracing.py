@@ -8,6 +8,7 @@ with the profiler on and off."""
 
 from __future__ import annotations
 
+import dataclasses
 from collections import Counter, defaultdict
 from pathlib import Path
 
@@ -188,6 +189,10 @@ def test_scheduler_spans_and_counters(engine, tmp_path):
     assert {st["capacity"] for st in steps} == {2 * sched.slot_capacity}
     assert sched.decode_positions == (4 + 6) + (5 + 7) + (6 + 5) + 6 + 7 + 8
     assert sched.capacity_positions == 6 * 2 * sched.slot_capacity
+    # dense-ref reads each slot's whole table on the paged step
+    assert sched.paged_steps == sched.steps_run
+    assert [st["read"] for st in steps] == [2 * sched.slot_capacity] * 6
+    assert sched.read_positions == sched.capacity_positions
     assert sched.tokens_emitted == 3 + 2 + 4
 
     untraced = make_scheduler(engine).run(stream(engine))
@@ -199,8 +204,21 @@ def test_scheduler_spans_and_counters(engine, tmp_path):
 
 def test_decode_step_scopes(engine):
     """The jitted step carries the pool, decode and sampling scopes as op
-    metadata, which the device trace reports per operation."""
+    metadata, which the device trace reports per operation.  The dense
+    family's step is paged: it writes the new token under
+    ``serve.pool_scatter`` and gathers nothing."""
     sched = make_scheduler(engine)
+    args = (sched.params, sched._tokens, sched._resident, sched.pool.buffers,
+            sched._tables_dev, sched._active_dev)
+    text = sched._step_fn.lower(*args).as_text(debug_info=True)
+    for scope in ("serve.pool_scatter", "serve.decode", "serve.sample"):
+        assert scope in text, scope
+    assert "serve.pool_gather" not in text
+    # the gathering step (the other families, the sharded variant)
+    gather = dataclasses.replace(engine.model, decode_paged=None)
+    sched = RequestScheduler(gather, engine.params, engine._prefill,
+                             num_slots=2, slot_capacity=sched.slot_capacity,
+                             layout=sched.layout)
     args = (sched.params, sched._tokens, sched._resident, sched.pool.buffers,
             sched._tables_dev, sched._active_dev)
     text = sched._step_fn.lower(*args).as_text(debug_info=True)
