@@ -38,6 +38,14 @@ class ModelConfig:
     moe_d_ff: int = 0
     moe_capacity_factor: float = 1.25
     first_dense_layers: int = 0       # deepseek-moe keeps layer 0 dense
+    # The gate: softmax over every routed expert, then the top
+    # ``experts_per_token``; their weights renormalised over the k chosen
+    # (True) or used as they are (False, deepseek-moe's norm_topk_prob).
+    moe_norm_topk: bool = True
+    # [first, stop) of the routed experts whose weights this model holds:
+    # one chip's share of an expert-parallel deployment.  The router keeps
+    # all ``n_experts`` outputs; None holds every expert.
+    held_experts: Optional[Tuple[int, int]] = None
     # --- SSM (mamba2 / SSD) ---
     ssm_state: int = 0
     ssm_heads: int = 0

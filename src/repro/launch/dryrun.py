@@ -183,10 +183,7 @@ def build_cell(arch: str, shape_name: str, mesh, strategy: str = "tp") -> Any:
         batch = input_specs(cfg, shape, abstract=True)
         bspecs = batch_pspecs(cfg, shape, batch, ax)
         max_len = shape.seq_len  # cache capacity = prompt length here
-        if cfg.family == "moe":
-            fn = lambda p, b: model.prefill(p, b, max_len, dp_groups=ax.dp_size)
-        else:
-            fn = lambda p, b: model.prefill(p, b, max_len)
+        fn = lambda p, b: model.prefill(p, b, max_len)
         cache_shape = cache_specs(cfg, shape, abstract=True)
         cspecs = cache_pspecs(cfg, shape, cache_shape, ax)
         _, cache_struct = jax.eval_shape(fn, pshape, batch)
@@ -210,10 +207,7 @@ def build_cell(arch: str, shape_name: str, mesh, strategy: str = "tp") -> Any:
     cache = cache_specs(cfg, shape, abstract=True)
     cspecs = cache_pspecs(cfg, shape, cache, ax)
     tok_spec = batch_pspecs(cfg, shape, batch, ax)
-    if cfg.family == "moe":
-        fn = lambda p, t, c: model.decode_step(p, t, c, dp_groups=1)
-    else:
-        fn = model.decode_step
+    fn = model.decode_step
     jf = jax.jit(
         fn,
         in_shardings=(to_named(mesh, pspecs), to_named(mesh, tok_spec["token"]),

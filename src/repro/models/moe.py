@@ -1,11 +1,13 @@
 """Mixture-of-Experts decoder (kimi-k2-1t, deepseek-moe-16b).
 
-Routing: top-k with normalized gate weights over the selected experts
-(DeepSeek-style), optional shared experts always active, and a dense first
-layer (``cfg.first_dense_layers``).
+Routing: a softmax gate over every routed expert and its top k, the k
+weights renormalised (kimi-k2) or used as they are (deepseek-moe,
+``cfg.moe_norm_topk``); optional shared experts always active, and a dense
+first layer (``cfg.first_dense_layers``).
 
-Dispatch is **grouped sort-based with static capacity** — the TPU-native
-adaptation of the paper's send-only-needed-rows insight (DESIGN.md §3):
+Training dispatches **grouped sort-based with static capacity** — the
+TPU-native adaptation of the paper's send-only-needed-rows insight
+(DESIGN.md §3):
 
 * tokens are split into ``dp_groups`` groups aligned with the data-parallel
   sharding, so the sort that ranks tokens within each expert never crosses a
@@ -18,6 +20,14 @@ adaptation of the paper's send-only-needed-rows insight (DESIGN.md §3):
   collective analogue of the FSI point-to-point exchange.
 
 Aux: load-balance loss (Switch-style) returned in metrics.
+
+Serving is **dropless** (:func:`moe_ffn_serve`): every token goes to its
+top k over all experts, and the layer computes exactly the assignments
+that land on the experts it holds (``cfg.held_experts``, one chip's share
+of an expert-parallel deployment; all of them by default), grouped by
+expert through the grouped matmul kernel (``kernels/moe_gmm``).  What the
+absent experts would add is left out; shared experts are computed here.
+A token's output depends only on the token, whatever else shares the step.
 """
 
 from __future__ import annotations
@@ -26,9 +36,11 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.backends import KVCacheLayout, get_backend
+from repro.kernels.moe_gmm.ops import moe_gmm
 from repro.models import layers as L
 from repro.models.attention import chunked_causal_attention
 from repro.models.kvcache import pad_kv_to_layout
@@ -37,29 +49,25 @@ from repro.models import transformer as TF
 PyTree = Any
 ACC = jnp.float32
 
-# MoE decode holds the latent/KV cache at fp32 (the PR 2 bisect: kimi-k2's
-# decode-vs-teacher-forcing drift came entirely from bf16 rounding of cached
-# K/V — the probability row is rounded against the cache dtype, and the MoE
-# router amplifies the rounding into ~2.5e-2 logit error on worst-case rows;
-# with an fp32 cache all attention backends produce bitwise-identical logits).
-# Costs 2× decode-cache memory for the MoE family only; the numerics story is
-# documented in docs/ARCHITECTURE.md §Numerics.
-DECODE_CACHE_DTYPE = jnp.float32
-
-
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
 
 
+def held_experts(cfg: ModelConfig) -> Tuple[int, int]:
+    """[first, stop) of the routed experts this model holds."""
+    return cfg.held_experts or (0, cfg.n_experts)
+
+
 def init_moe_ffn(key, cfg: ModelConfig) -> PyTree:
     d, f, E = cfg.d_model, cfg.moe_d_ff, cfg.n_experts
+    e0, e1 = held_experts(cfg)
     ks = jax.random.split(key, 5)
     p = {
         "router": L.dense_init(ks[0], (d, E), dtype=jnp.float32),
-        "w_gate": L.dense_init(ks[1], (E, d, f)),
-        "w_up": L.dense_init(ks[2], (E, d, f)),
-        "w_down": L.dense_init(ks[3], (E, f, d), in_axis_size=f),
+        "w_gate": L.dense_init(ks[1], (e1 - e0, d, f)),
+        "w_up": L.dense_init(ks[2], (e1 - e0, d, f)),
+        "w_down": L.dense_init(ks[3], (e1 - e0, f, d), in_axis_size=f),
     }
     if cfg.n_shared_experts:
         p["shared"] = L.init_mlp(ks[4], d, f * cfg.n_shared_experts)
@@ -119,11 +127,25 @@ def init(key, cfg: ModelConfig) -> PyTree:
 # ---------------------------------------------------------------------------
 
 
-def route_topk(logits: jnp.ndarray, k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """[T, E] → (weights [T, k] normalized, idx [T, k])."""
-    vals, idx = jax.lax.top_k(logits, k)
-    w = jax.nn.softmax(vals.astype(ACC), axis=-1)
+def route_topk(logits: jnp.ndarray, k: int, normalize: bool = True
+               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """[T, E] → (weights [T, k], idx [T, k]): the k largest of the softmax
+    over all E experts.  ``normalize`` renormalises their weights to sum to
+    1 (a softmax over the k chosen logits); otherwise they are the
+    probabilities as they are (DeepSeekMoE's ``norm_topk_prob`` false)."""
+    if normalize:
+        vals, idx = jax.lax.top_k(logits, k)
+        return jax.nn.softmax(vals.astype(ACC), axis=-1), idx
+    w, idx = jax.lax.top_k(jax.nn.softmax(logits.astype(ACC), axis=-1), k)
     return w, idx
+
+
+def _route(p: PyTree, xf: jnp.ndarray, cfg: ModelConfig):
+    """Router logits [T, E] (f32) and the gate (weights, idx [T, k])."""
+    logits = jnp.einsum("td,de->te", xf.astype(ACC), p["router"],
+                        preferred_element_type=ACC)
+    return (logits,) + route_topk(logits, cfg.experts_per_token,
+                                  cfg.moe_norm_topk)
 
 
 def _dispatch_tables(e_flat: jnp.ndarray, E: int, C: int):
@@ -163,9 +185,7 @@ def moe_ffn(
     Tg = T // dp_groups
     C = max(1, int(-(-Tg * k // E) * cfg.moe_capacity_factor))
 
-    logits = jnp.einsum("td,de->te", xf.astype(ACC), p["router"],
-                        preferred_element_type=ACC)
-    w, idx = route_topk(logits, k)                   # [T,k]
+    logits, w, idx = _route(p, xf, cfg)               # [T,k]
 
     # group-local dispatch (vmapped over dp groups — sort never crosses the
     # data-parallel shard boundary)
@@ -246,9 +266,7 @@ def _moe_ffn_local(p_local, x, cfg: ModelConfig, e0, E_local: int):
     T = B * S
     C = max(1, int(-(-T * k // E) * cfg.moe_capacity_factor))
 
-    logits = jnp.einsum("td,de->te", xf.astype(ACC), p_local["router"],
-                        preferred_element_type=ACC)
-    w, idx = route_topk(logits, k)
+    logits, w, idx = _route(p_local, xf, cfg)
 
     # keep only assignments owned by this rank; foreign ones → sentinel
     e_rel = idx.reshape(-1) - e0
@@ -274,6 +292,62 @@ def _moe_ffn_local(p_local, x, cfg: ModelConfig, e0, E_local: int):
     counts = jnp.zeros((E,), ACC).at[idx.reshape(-1)].add(1.0)
     lb = E * jnp.sum((counts / (T * k)) * jax.nn.softmax(logits, -1).mean(0))
     return out.reshape(B, S, d), lb
+
+
+def held_part(p: PyTree, xf: jnp.ndarray, w: jnp.ndarray, idx: jnp.ndarray,
+              e0, layer=None, rows_valid=None
+              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The part of the routed experts' output that the experts held here
+    give: tokens ``xf`` [T, d] routed to ``idx`` [T, k] with gate weights
+    ``w``, the experts ``[e0, e0 + E_held)`` holding ``p``'s weights (a
+    stack of layers' [L, E_held, ...] given ``layer``, which the kernel
+    reads in place).  ``rows_valid`` (bool [T], all by default) marks the
+    tokens that are real; the others (a decode step's vacant slots) are
+    neither computed nor counted, and their output is zero.
+    Dropless: every assignment of a real token to a held expert is
+    computed, grouped by expert through the grouped matmul; assignments to
+    other experts are left out.  Returns (out f32 [T, d], counts int32
+    [2]: the held assignments computed and the held experts with at least
+    one, i.e. the experts whose weights the call read)."""
+    T, d = xf.shape
+    k = idx.shape[1]
+    n_held = p["w_gate"].shape[-3]
+    with jax.named_scope("moe.route"):
+        rel = idx.reshape(-1) - e0
+        held = (rel >= 0) & (rel < n_held)
+        if rows_valid is not None:
+            held = held & jnp.repeat(rows_valid, k)
+        expert = jnp.where(held, rel, n_held)
+        order = jnp.argsort(expert, stable=True)
+        sizes = jnp.zeros((n_held,), jnp.int32).at[expert].add(1, mode="drop")
+        rows = xf[order // k]
+    with jax.named_scope("moe.experts"):
+        y = moe_gmm(rows, sizes, p["w_gate"], p["w_up"], p["w_down"], layer)
+    with jax.named_scope("moe.combine"):
+        y = jnp.zeros_like(y).at[order].set(y).reshape(T, k, d)
+        out = jnp.sum(y * w[..., None].astype(ACC), axis=1)
+    counts = jnp.stack([sizes.sum(), jnp.count_nonzero(sizes)])
+    return out, counts.astype(jnp.int32)
+
+
+def moe_ffn_serve(p: PyTree, x: jnp.ndarray, cfg: ModelConfig, layer=None,
+                  rows_valid=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The serving expert layer: x [B, S, d] → (out [B, S, d], counts of
+    :func:`held_part`).  Every token is routed to its top k over all
+    ``n_experts``; the held experts' part and the shared experts make the
+    output.  Given ``layer``, ``p``'s routed experts are the stack of
+    every expert layer's; ``rows_valid`` (bool [B * S]) as
+    :func:`held_part` takes it."""
+    B, S, d = x.shape
+    xf = x.reshape(B * S, d)
+    with jax.named_scope("moe.route"):
+        _, w, idx = _route(p, xf, cfg)
+    out, counts = held_part(p, xf, w, idx, held_experts(cfg)[0], layer,
+                            rows_valid)
+    if cfg.n_shared_experts:
+        with jax.named_scope("moe.experts"):
+            out = out + L.mlp(p["shared"], x).reshape(B * S, d).astype(ACC)
+    return out.reshape(B, S, d).astype(x.dtype), counts
 
 
 def moe_ffn_shardmap(p: PyTree, x: jnp.ndarray, cfg: ModelConfig):
@@ -376,69 +450,91 @@ def loss_fn(params: PyTree, batch, cfg: ModelConfig, dp_groups: int = 1,
 
 
 def _stacked_blocks(params):
-    """(blocks pytree, n) iterator helper over dense+moe stacks."""
-    out = []
-    if params.get("dense_blocks") is not None:
-        out.append(params["dense_blocks"])
-    out.append(params["moe_blocks"])
-    return out
+    """The layer stacks present, in order: the dense stack (if any), then
+    the moe stack (if any)."""
+    return [params[name] for name in ("dense_blocks", "moe_blocks")
+            if params.get(name) is not None]
 
 
-def prefill(params: PyTree, tokens: jnp.ndarray, cfg: ModelConfig,
-            max_len: int, dp_groups: int = 1,
-            layout: KVCacheLayout = KVCacheLayout()) -> Tuple[jnp.ndarray, PyTree]:
-    x = L.embed_tokens(params["embed"], tokens)
+# The expert layer's device counters (:func:`held_part`), in order.
+COUNTERS = ("moe_rows", "moe_groups_read")
+NO_COUNTS = np.zeros((len(COUNTERS),), np.int32)
+
+
+_EXPERTS = ("w_gate", "w_up", "w_down")
+
+
+def _split_experts(blocks: PyTree) -> Tuple[PyTree, Optional[PyTree]]:
+    """A stack of layers without its routed experts' weights, which a
+    layer scan would slice (a copy per layer, as the kernel cannot fuse
+    the slice), and those weights, whole, for the kernel to read its
+    layer's in place.  (blocks, None) for the dense stack."""
+    if "moe" not in blocks:
+        return blocks, None
+    moe = blocks["moe"]
+    rest = {k: v for k, v in moe.items() if k not in _EXPERTS}
+    return {**blocks, "moe": rest}, {k: moe[k] for k in _EXPERTS}
+
+
+def _ffn(block: PyTree, h: jnp.ndarray, cfg: ModelConfig, experts, layer,
+         rows_valid=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """A serving layer's feed-forward with its residual: the dense MLP in
+    the dense stack, the expert layer in the moe stack (``experts``, the
+    stack's routed experts, of which layer ``layer``'s; ``rows_valid`` as
+    :func:`held_part` takes it).  Returns (h, the expert layer's counts,
+    zero for a dense layer)."""
+    hm = L.rms_norm(h, block["ln_mlp"], cfg.norm_eps)
+    if experts is None:
+        return h + L.mlp(block["mlp"], hm), NO_COUNTS
+    out, counts = moe_ffn_serve({**block["moe"], **experts}, hm, cfg, layer,
+                                rows_valid)
+    return h + out, counts
+
+
+def _prefill_layers(stacks, x: jnp.ndarray, cfg: ModelConfig, max_len: int,
+                    layout: KVCacheLayout):
+    """Prefill ``x`` [B, S, d] through each stack; returns (x, the stacks'
+    KV caches, the expert layers' counts summed)."""
     B, S, _ = x.shape
     positions = jnp.arange(S)[None, :].repeat(B, axis=0)
-    caches = []
+    caches, counts = [], NO_COUNTS
 
-    for blocks in _stacked_blocks(params):
-        def body(h, blk):
+    for blocks in stacks:
+        blocks, experts = _split_experts(blocks)
+
+        def body(carry, inp):
+            h, acc = carry
+            blk, layer = inp
             hn = L.rms_norm(h, blk["ln_attn"], cfg.norm_eps)
             q, k, v = L.qkv_project(blk["attn"], hn)
             q = L.apply_rope(q, positions, cfg.rope_theta)
             k = L.apply_rope(k, positions, cfg.rope_theta)
             o = chunked_causal_attention(q, k, v)
             h = h + L.out_project(blk["attn"], o, h.dtype)
-            hm = L.rms_norm(h, blk["ln_mlp"], cfg.norm_eps)
-            if blk.get("mlp") is not None:
-                h = h + L.mlp(blk["mlp"], hm)
-            else:
-                out, _ = moe_ffn_dispatch(blk["moe"], hm, cfg, dp_groups)
-                h = h + out
-            k_pad = pad_kv_to_layout(k, max_len, layout)
-            v_pad = pad_kv_to_layout(v, max_len, layout)
-            return h, (k_pad.astype(DECODE_CACHE_DTYPE),
-                       v_pad.astype(DECODE_CACHE_DTYPE))
+            h, c = _ffn(blk, h, cfg, experts, layer)
+            return (h, acc + c), (pad_kv_to_layout(k, max_len, layout),
+                                  pad_kv_to_layout(v, max_len, layout))
 
         if cfg.remat:
             body = jax.checkpoint(body, prevent_cse=False)
-        x, (ks, vs) = jax.lax.scan(body, x, blocks)
+        (x, counts), (ks, vs) = jax.lax.scan(
+            body, (x, counts), (blocks, _layers(blocks)))
         caches.append({"k": ks, "v": vs})
-
-    x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
-    table = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    logits = L.unembed(x[:, -1:], table)
-    cache = {"stacks": caches, "length": jnp.asarray(S, jnp.int32)}
-    return logits, cache
+    return x, caches, counts
 
 
-def decode_step(params: PyTree, token: jnp.ndarray, cache: PyTree,
-                cfg: ModelConfig, dp_groups: int = 1,
-                attn_backend=None, seq_shard_axes=None,
-                layout: Optional[KVCacheLayout] = None) -> Tuple[jnp.ndarray, PyTree]:
-    attn = get_backend("attention", attn_backend)
-    if layout is not None:
-        layout.check_capacity(int(cache["stacks"][-1]["k"].shape[3]))
-    x = L.embed_tokens(params["embed"], token)
-    B = x.shape[0]
-    pos = cache["length"]
-    positions = jnp.full((B, 1), pos, dtype=jnp.int32)
-    new_stacks = []
+def _decode_layers(stacks, kv_stacks, x: jnp.ndarray, pos, attn,
+                   seq_shard_axes, cfg: ModelConfig):
+    """One decode position through each stack over contiguous caches;
+    returns (x, the updated stacks, the expert layers' counts summed)."""
+    positions = jnp.full((x.shape[0], 1), pos, dtype=jnp.int32)
+    new_stacks, counts = [], NO_COUNTS
+    for blocks, kv in zip(stacks, kv_stacks):
+        blocks, experts = _split_experts(blocks)
 
-    for blocks, kv in zip(_stacked_blocks(params), cache["stacks"]):
-        def body(h, inp):
-            blk, k_cache, v_cache = inp
+        def body(carry, inp):
+            h, acc = carry
+            blk, layer, k_cache, v_cache = inp
             hn = L.rms_norm(h, blk["ln_attn"], cfg.norm_eps)
             q, k, v = L.qkv_project(blk["attn"], hn)
             q = L.apply_rope(q, positions, cfg.rope_theta)
@@ -446,21 +542,87 @@ def decode_step(params: PyTree, token: jnp.ndarray, cache: PyTree,
             o, k_cache, v_cache = TF._decode_attn(
                 attn, q, k, v, k_cache, v_cache, pos, seq_shard_axes)
             h = h + L.out_project(blk["attn"], o.astype(h.dtype), h.dtype)
-            hm = L.rms_norm(h, blk["ln_mlp"], cfg.norm_eps)
-            if blk.get("mlp") is not None:
-                h = h + L.mlp(blk["mlp"], hm)
-            else:
-                out, _ = moe_ffn_dispatch(blk["moe"], hm, cfg, dp_groups)
-                h = h + out
-            return h, (k_cache, v_cache)
+            h, c = _ffn(blk, h, cfg, experts, layer)
+            return (h, acc + c), (k_cache, v_cache)
 
-        x, (ks, vs) = jax.lax.scan(body, x, (blocks, kv["k"], kv["v"]))
+        (x, counts), (ks, vs) = jax.lax.scan(
+            body, (x, counts), (blocks, _layers(blocks), kv["k"], kv["v"]))
         new_stacks.append({"k": ks, "v": vs})
+    return x, new_stacks, counts
 
+
+def _layers(blocks: PyTree) -> jnp.ndarray:
+    """Each layer's index in its stack, for a layer scan."""
+    n = jax.tree.leaves(blocks)[0].shape[0]
+    return jnp.arange(n, dtype=jnp.int32)
+
+
+def _head(params: PyTree, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
     table = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    logits = L.unembed(x, table)
-    return logits, {"stacks": new_stacks, "length": pos + 1}
+    return L.unembed(x, table)
+
+
+def prefill_counted(params: PyTree, tokens: jnp.ndarray, cfg: ModelConfig,
+                    max_len: int, layout: KVCacheLayout = KVCacheLayout()
+                    ) -> Tuple[jnp.ndarray, PyTree, jnp.ndarray]:
+    """The prompt through every layer: (logits, the cache, each stack's KV
+    in the activations' type, and the expert layers' counts over the
+    prompt, int32 in the order of :data:`COUNTERS`)."""
+    x = L.embed_tokens(params["embed"], tokens)
+    x, caches, counts = _prefill_layers(_stacked_blocks(params), x, cfg,
+                                        max_len, layout)
+    cache = {"stacks": caches, "length": jnp.asarray(x.shape[1], jnp.int32)}
+    return _head(params, x[:, -1:], cfg), cache, counts
+
+
+def prefill(params: PyTree, tokens: jnp.ndarray, cfg: ModelConfig,
+            max_len: int,
+            layout: KVCacheLayout = KVCacheLayout()) -> Tuple[jnp.ndarray, PyTree]:
+    """:func:`prefill_counted` without the counts."""
+    logits, cache, _ = prefill_counted(params, tokens, cfg, max_len, layout)
+    return logits, cache
+
+
+def decode_step(params: PyTree, token: jnp.ndarray, cache: PyTree,
+                cfg: ModelConfig, attn_backend=None, seq_shard_axes=None,
+                layout: Optional[KVCacheLayout] = None) -> Tuple[jnp.ndarray, PyTree]:
+    attn = get_backend("attention", attn_backend)
+    if layout is not None:
+        layout.check_capacity(int(cache["stacks"][-1]["k"].shape[3]))
+    x = L.embed_tokens(params["embed"], token)
+    pos = cache["length"]
+    x, new_stacks, _ = _decode_layers(
+        _stacked_blocks(params), cache["stacks"], x, pos, attn,
+        seq_shard_axes, cfg)
+    return _head(params, x, cfg), {"stacks": new_stacks, "length": pos + 1}
+
+
+def decode_step_paged(params: PyTree, token: jnp.ndarray, cache: PyTree,
+                      tables: jnp.ndarray, write, active: jnp.ndarray,
+                      cfg: ModelConfig, *, attn_backend=None
+                      ) -> Tuple[jnp.ndarray, PyTree, jnp.ndarray]:
+    """One decode step of ``slots`` requests over the paged KV pool, as
+    :func:`repro.models.transformer.decode_step_paged` with the expert
+    layer as the moe stack's feed-forward.  ``cache["stacks"]`` holds each
+    stack's pool buffers; ``active`` (bool [slots]) marks the slots that
+    hold a request, and the expert layer computes the routed experts of
+    those only.  Returns (logits, the cache, the expert layers' counts
+    over the step)."""
+    attn = get_backend("attention", attn_backend)
+    x = L.embed_tokens(params["embed"], token)
+    pos = cache["length"]
+    new_stacks, counts = [], NO_COUNTS
+    for blocks, kv in zip(_stacked_blocks(params), cache["stacks"]):
+        blocks, experts = _split_experts(blocks)
+        x, ks, vs, counts = TF.paged_layers(
+            blocks, x, kv["k"], kv["v"], tables, pos, write, attn, cfg,
+            lambda block, h, layer: _ffn(block, h, cfg, experts, layer,
+                                         active),
+            counts)
+        new_stacks.append({"k": ks, "v": vs})
+    new_cache = {**cache, "stacks": new_stacks, "length": pos + 1}
+    return _head(params, x, cfg), new_cache, counts
 
 
 # ---------------------------------------------------------------------------
@@ -507,102 +669,36 @@ def slice_stage_params(params: PyTree, spec, cfg: ModelConfig) -> PyTree:
     return out
 
 
-def _present_stacks(sp: PyTree):
-    out = []
-    if sp.get("dense_blocks") is not None:
-        out.append(sp["dense_blocks"])
-    if sp.get("moe_blocks") is not None:
-        out.append(sp["moe_blocks"])
-    return out
-
-
 def stage_prefill(
     sp: PyTree, spec, x_in: jnp.ndarray, cfg: ModelConfig, max_len: int,
-    dp_groups: int = 1,
     layout: KVCacheLayout = KVCacheLayout(),
 ) -> Tuple[jnp.ndarray, PyTree]:
     """One stage of ``prefill`` — token ids [B, S] in on the embedding stage,
     hidden states [B, S, d] otherwise; logits [B, 1, V] out on the head
     stage.  The stage's KV stacks stay resident in its cache."""
-    if spec.has_embed:
-        x = L.embed_tokens(sp["embed"], x_in)
-    else:
-        x = x_in
-    B, S, _ = x.shape
-    positions = jnp.arange(S)[None, :].repeat(B, axis=0)
-    caches = []
-
-    for blocks in _present_stacks(sp):
-        def body(h, blk):
-            hn = L.rms_norm(h, blk["ln_attn"], cfg.norm_eps)
-            q, k, v = L.qkv_project(blk["attn"], hn)
-            q = L.apply_rope(q, positions, cfg.rope_theta)
-            k = L.apply_rope(k, positions, cfg.rope_theta)
-            o = chunked_causal_attention(q, k, v)
-            h = h + L.out_project(blk["attn"], o, h.dtype)
-            hm = L.rms_norm(h, blk["ln_mlp"], cfg.norm_eps)
-            if blk.get("mlp") is not None:
-                h = h + L.mlp(blk["mlp"], hm)
-            else:
-                out, _ = moe_ffn_dispatch(blk["moe"], hm, cfg, dp_groups)
-                h = h + out
-            k_pad = pad_kv_to_layout(k, max_len, layout)
-            v_pad = pad_kv_to_layout(v, max_len, layout)
-            return h, (k_pad.astype(DECODE_CACHE_DTYPE),
-                       v_pad.astype(DECODE_CACHE_DTYPE))
-
-        if cfg.remat:
-            body = jax.checkpoint(body, prevent_cse=False)
-        x, (ks, vs) = jax.lax.scan(body, x, blocks)
-        caches.append({"k": ks, "v": vs})
-
-    cache = {"stacks": caches, "length": jnp.asarray(S, jnp.int32)}
+    x = L.embed_tokens(sp["embed"], x_in) if spec.has_embed else x_in
+    x, caches, _ = _prefill_layers(_stacked_blocks(sp), x, cfg, max_len,
+                                   layout)
+    cache = {"stacks": caches, "length": jnp.asarray(x.shape[1], jnp.int32)}
     if spec.has_head:
-        x = L.rms_norm(x[:, -1:], sp["ln_f"], cfg.norm_eps)
-        table = sp["embed"] if cfg.tie_embeddings else sp["unembed"]
-        return L.unembed(x, table), cache
+        return _head(sp, x[:, -1:], cfg), cache
     return x, cache
 
 
 def stage_decode_step(
     sp: PyTree, spec, x_in: jnp.ndarray, cache: PyTree, cfg: ModelConfig,
-    dp_groups: int = 1, *, attn_backend=None,
+    *, attn_backend=None,
 ) -> Tuple[jnp.ndarray, PyTree]:
     """One stage of ``decode_step`` — token [B, 1] in on the embedding stage,
     hidden [B, 1, d] otherwise; logits [B, 1, V] out on the head stage."""
     attn = get_backend("attention", attn_backend)
     x = L.embed_tokens(sp["embed"], x_in) if spec.has_embed else x_in
-    B = x.shape[0]
     pos = cache["length"]
-    positions = jnp.full((B, 1), pos, dtype=jnp.int32)
-    new_stacks = []
-
-    for blocks, kv in zip(_present_stacks(sp), cache["stacks"]):
-        def body(h, inp):
-            blk, k_cache, v_cache = inp
-            hn = L.rms_norm(h, blk["ln_attn"], cfg.norm_eps)
-            q, k, v = L.qkv_project(blk["attn"], hn)
-            q = L.apply_rope(q, positions, cfg.rope_theta)
-            k = L.apply_rope(k, positions, cfg.rope_theta)
-            o, k_cache, v_cache = TF._decode_attn(
-                attn, q, k, v, k_cache, v_cache, pos, None)
-            h = h + L.out_project(blk["attn"], o.astype(h.dtype), h.dtype)
-            hm = L.rms_norm(h, blk["ln_mlp"], cfg.norm_eps)
-            if blk.get("mlp") is not None:
-                h = h + L.mlp(blk["mlp"], hm)
-            else:
-                out, _ = moe_ffn_dispatch(blk["moe"], hm, cfg, dp_groups)
-                h = h + out
-            return h, (k_cache, v_cache)
-
-        x, (ks, vs) = jax.lax.scan(body, x, (blocks, kv["k"], kv["v"]))
-        new_stacks.append({"k": ks, "v": vs})
-
+    x, new_stacks, _ = _decode_layers(_stacked_blocks(sp), cache["stacks"],
+                                      x, pos, attn, None, cfg)
     new_cache = {"stacks": new_stacks, "length": pos + 1}
     if spec.has_head:
-        x = L.rms_norm(x, sp["ln_f"], cfg.norm_eps)
-        table = sp["embed"] if cfg.tie_embeddings else sp["unembed"]
-        return L.unembed(x, table), new_cache
+        return _head(sp, x, cfg), new_cache
     return x, new_cache
 
 

@@ -36,13 +36,22 @@ class ModelApi:
     # is_leaf=lambda x: x is None.
     cache_seq_axes: Callable[[PyTree], PyTree] = None
     # Batched decode over the paged KV pool, one position per slot:
-    # ``(params, tokens [slots, 1], cache, tables [slots, W], write) ->
-    # (logits [slots, 1, V], cache)`` where ``cache`` holds the pool's
-    # buffers at the paged leaves and per-slot state elsewhere.  None where
-    # the family decodes gathered per-slot caches only.
-    decode_paged: Optional[Callable[..., Tuple[jnp.ndarray, PyTree]]] = None
+    # ``(params, tokens [slots, 1], cache, tables [slots, W], write, active
+    # [slots]) -> (logits [slots, 1, V], cache, counts)`` where ``cache``
+    # holds the pool's buffers at the paged leaves and per-slot state
+    # elsewhere, and ``counts`` is the step's device counters (None where
+    # the family names none).  None where the family decodes gathered
+    # per-slot caches only.
+    decode_paged: Optional[Callable[..., Tuple[jnp.ndarray, PyTree, Any]]] = None
     # The decode-attention backend ``decode_paged`` calls.
     attn_backend: Any = None
+    # Names of the family's device counters: the int32 vector, in this
+    # order, that ``prefill_counted`` and ``decode_paged`` return beside
+    # their outputs.
+    counters: Tuple[str, ...] = ()
+    # ``prefill`` that also returns the prompt's counters: ``(params, batch,
+    # max_len) -> (logits, cache, counts)``.  None where there are none.
+    prefill_counted: Optional[Callable[..., Tuple[jnp.ndarray, PyTree, Any]]] = None
 
 
 def get_model(cfg: ModelConfig, attn_backend=None) -> ModelApi:
@@ -74,9 +83,9 @@ def get_model(cfg: ModelConfig, attn_backend=None) -> ModelApi:
             decode_step=lambda p, t, c, **kw: transformer.decode_step(
                 p, t, c, cfg, attn_backend=attn, **kw),
             cache_seq_axes=transformer.cache_seq_axes,
-            decode_paged=lambda p, t, c, tables, write:
-                transformer.decode_step_paged(p, t, c, tables, write, cfg,
-                                              attn_backend=attn),
+            decode_paged=lambda p, t, c, tables, write, active: (
+                *transformer.decode_step_paged(p, t, c, tables, write, cfg,
+                                               attn_backend=attn), None),
             attn_backend=attn,
         )
     if fam == "vlm":
@@ -92,9 +101,9 @@ def get_model(cfg: ModelConfig, attn_backend=None) -> ModelApi:
             decode_step=lambda p, t, c, **kw: transformer.decode_step(
                 p, t, c, cfg, attn_backend=attn, **kw),
             cache_seq_axes=transformer.cache_seq_axes,
-            decode_paged=lambda p, t, c, tables, write:
-                transformer.decode_step_paged(p, t, c, tables, write, cfg,
-                                              attn_backend=attn),
+            decode_paged=lambda p, t, c, tables, write, active: (
+                *transformer.decode_step_paged(p, t, c, tables, write, cfg,
+                                               attn_backend=attn), None),
             attn_backend=attn,
         )
     if fam == "moe":
@@ -104,12 +113,18 @@ def get_model(cfg: ModelConfig, attn_backend=None) -> ModelApi:
             loss_fn=lambda p, b, dp_groups=1: moe.loss_fn(p, b, cfg, dp_groups),
             forward=lambda p, b, dp_groups=1: moe.forward(
                 p, b["tokens"], cfg, dp_groups)[0],
-            prefill=lambda p, b, max_len, dp_groups=1: moe.prefill(
-                p, b["tokens"], cfg, max_len, dp_groups,
-                layout=layout(max_len)),
-            decode_step=lambda p, t, c, dp_groups=1, **kw: moe.decode_step(
-                p, t, c, cfg, dp_groups, attn_backend=attn, **kw),
+            prefill=lambda p, b, max_len: moe.prefill(
+                p, b["tokens"], cfg, max_len, layout=layout(max_len)),
+            decode_step=lambda p, t, c, **kw: moe.decode_step(
+                p, t, c, cfg, attn_backend=attn, **kw),
             cache_seq_axes=moe.cache_seq_axes,
+            decode_paged=lambda p, t, c, tables, write, active:
+                moe.decode_step_paged(p, t, c, tables, write, active, cfg,
+                                      attn_backend=attn),
+            attn_backend=attn,
+            counters=moe.COUNTERS,
+            prefill_counted=lambda p, b, max_len: moe.prefill_counted(
+                p, b["tokens"], cfg, max_len, layout=layout(max_len)),
         )
     if fam == "ssm":
         return ModelApi(

@@ -237,6 +237,40 @@ def decode_step(
     return logits, new_cache
 
 
+def paged_layers(blocks: PyTree, x: jnp.ndarray, k_pages: jnp.ndarray,
+                 v_pages: jnp.ndarray, tables: jnp.ndarray, pos: jnp.ndarray,
+                 write, attn, cfg: ModelConfig, ffn, counts=None):
+    """One stack of layers of the paged decode step, each slot at its own
+    position ``pos`` [slots].  Per layer: write each slot's new K/V row
+    into its page (``write(leaf, layer, new)``, before attending, so
+    attention sees the new token), attend through the backend's
+    ``decode_paged`` over ``tables``, then ``ffn(block, h) -> (h,
+    counts)``: the family's feed-forward and what it counted (None where
+    it counts nothing), summed over the stack onto ``counts``.  Returns
+    (x, k_pages, v_pages, counts)."""
+    positions = pos[:, None]
+
+    def body(carry, inp):
+        h, k_pages, v_pages, acc = carry
+        block, layer = inp
+        hn = L.rms_norm(h, block["ln_attn"], cfg.norm_eps)
+        q, k, v = L.qkv_project(block["attn"], hn)
+        q = L.apply_rope(q, positions, cfg.rope_theta)
+        k = L.apply_rope(k, positions, cfg.rope_theta)
+        k_pages = write(k_pages, layer, k)
+        v_pages = write(v_pages, layer, v)
+        o = attn.decode_paged(q, k_pages, v_pages, tables, pos + 1, layer)
+        h = h + L.out_project(block["attn"], o.astype(h.dtype), h.dtype)
+        h, c = ffn(block, h, layer)
+        return (h, k_pages, v_pages, acc if c is None else acc + c), None
+
+    n_layers = k_pages.shape[0]
+    (x, ks, vs, counts), _ = jax.lax.scan(
+        body, (x, k_pages, v_pages, counts),
+        (blocks, jnp.arange(n_layers, dtype=jnp.int32)))
+    return x, ks, vs, counts
+
+
 def decode_step_paged(
     params: PyTree, token: jnp.ndarray, cache: PyTree, tables: jnp.ndarray,
     write, cfg: ModelConfig, *, attn_backend=None,
@@ -248,33 +282,15 @@ def decode_step_paged(
     block_k, D]`` (``serving/kv_pool.py``), ``"length"`` int32 [slots], each
     slot's position.  ``tables``: int32 [slots, W], each slot's pages.
     ``write(leaf, layer, new [slots, 1, KV, D])`` puts each slot's new K or
-    V row at its position in its page and returns the leaf; each layer
-    writes before it attends, so attention sees the new token.  The
-    backend's ``decode_paged`` reads the pages through ``tables``.
+    V row at its position in its page and returns the leaf
+    (:func:`paged_layers`).
     """
     attn = get_backend("attention", attn_backend)
     x = L.embed_tokens(params["embed"], token)
     pos = cache["length"]
-    positions = pos[:, None]
-
-    def body(carry, inp):
-        h, k_pages, v_pages = carry
-        block, layer = inp
-        hn = L.rms_norm(h, block["ln_attn"], cfg.norm_eps)
-        q, k, v = L.qkv_project(block["attn"], hn)
-        q = L.apply_rope(q, positions, cfg.rope_theta)
-        k = L.apply_rope(k, positions, cfg.rope_theta)
-        k_pages = write(k_pages, layer, k)
-        v_pages = write(v_pages, layer, v)
-        o = attn.decode_paged(q, k_pages, v_pages, tables, pos + 1, layer)
-        h = h + L.out_project(block["attn"], o.astype(h.dtype), h.dtype)
-        h = _mlp_apply(block, h, cfg)
-        return (h, k_pages, v_pages), None
-
-    n_layers = cache["k"].shape[0]
-    (x, ks, vs), _ = jax.lax.scan(
-        body, (x, cache["k"], cache["v"]),
-        (params["blocks"], jnp.arange(n_layers, dtype=jnp.int32)))
+    x, ks, vs, _ = paged_layers(
+        params["blocks"], x, cache["k"], cache["v"], tables, pos, write, attn,
+        cfg, lambda block, h, layer: (_mlp_apply(block, h, cfg), None))
     x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
     table = params["embed"] if cfg.tie_embeddings else params["unembed"]
     logits = L.unembed(x, table)

@@ -67,7 +67,10 @@ class ServingEngine:
         self.model = get_model(cfg, attn_backend=self.attn_backend)
         self.params = params if params is not None else self.model.init(
             jax.random.key(seed))
-        self._prefill = jax.jit(self.model.prefill, static_argnums=(2,))
+        # The scheduler's prefill, with the family's counters where it
+        # names any (``ModelApi.prefill_counted``).
+        self._prefill = jax.jit(self.model.prefill_counted
+                                or self.model.prefill, static_argnums=(2,))
         self._decode = jax.jit(self.model.decode_step)
 
     def cache_layout(self, max_len: int) -> KVCacheLayout:
@@ -95,7 +98,7 @@ class ServingEngine:
         batch: Dict[str, Any] = {"tokens": jnp.asarray(prompts, jnp.int32)}
         if extra:
             batch.update({k: jnp.asarray(v) for k, v in extra.items()})
-        logits, cache = self._prefill(self.params, batch, max_len)
+        logits, cache = self._prefill(self.params, batch, max_len)[:2]
         out_tokens = []
         token = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
         for _ in range(max_new_tokens):

@@ -12,10 +12,10 @@ the paper's §V-B buffering made per-request):
 * KV in the :class:`KVBlockPool`, reached through block tables, so a
   request's pages are scattered physically but contiguous logically
   (defrag-free reuse).  Where the model offers a paged decode
-  (``ModelApi.decode_paged``: the dense and vlm families) and the step is
-  not sequence-sharded, attention reads the pages in place and the step
-  writes only each slot's new token; every other step gathers each slot's
-  cache and scatters the new token back;
+  (``ModelApi.decode_paged``: the dense, vlm and moe families) and the
+  step is not sequence-sharded, attention reads the pages in place and
+  the step writes only each slot's new token; every other step gathers
+  each slot's cache and scatters the new token back;
 * per-slot ``length`` — the paged decode takes one position per slot, and
   on the gather path the vmap over slots turns every family's scalar
   ``length`` into one length per request *without touching family decode
@@ -41,7 +41,7 @@ already gates.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -95,7 +95,8 @@ class RequestScheduler:
     """Continuous batching over ``num_slots`` fixed decode slots.
 
     ``model`` is a :class:`repro.models.registry.ModelApi`; ``prefill_fn``
-    is a jitted ``(params, batch, max_len_static) -> (logits, cache)`` (the
+    is a jitted ``(params, batch, max_len_static) -> (logits, cache)``, or
+    ``(logits, cache, counts)`` where the family names counters (the
     engine shares its own).  ``slot_capacity`` is the static per-slot cache
     capacity — every admitted request prefills at this capacity so gathered
     shapes are constant; it must be a ``layout.block_k`` multiple.
@@ -153,6 +154,17 @@ class RequestScheduler:
         self._active_dev = jnp.asarray(self._active)
         self._slots: List[Optional[_Slot]] = [None] * self.num_slots
         self.paged = _takes_paged_step(model, mesh)
+        # The family's device counters (``ModelApi.counters``) on the paged
+        # step, summed over the stream on the device under the scheduler's
+        # ``resident["counts"]``: each admission adds its prefill's, each
+        # step its own.  ``run`` reads them into ``device_counters`` when
+        # it returns.  The gather step counts nothing.
+        counted = self.paged and bool(model.counters)
+        self.device_counters = dict.fromkeys(
+            model.counters if counted else (), 0)
+        if counted:
+            self._resident["counts"] = jnp.zeros((len(model.counters),),
+                                                 jnp.int32)
         self._step_fn = self._build_step()
         # Attention reads up to each slot's last valid page, or the whole
         # table where the backend gathers it.
@@ -179,8 +191,8 @@ class RequestScheduler:
     def _template_cache(self) -> PyTree:
         batch = {"tokens": jnp.zeros((1, 1), jnp.int32)}
         batch.update(self._template_extra())
-        _, cache = self._prefill(self.params, batch, self.slot_capacity)
-        return cache
+        return _prefilled(self._prefill(self.params, batch,
+                                        self.slot_capacity))[1]
 
     def _template_extra(self) -> Dict[str, jnp.ndarray]:
         cfg = self.model.cfg
@@ -203,8 +215,8 @@ class RequestScheduler:
         if req.extra:
             batch.update({k: jnp.asarray(v) for k, v in req.extra.items()})
         with span("serve.prefill", rid=req.rid):
-            logits, cache = self._prefill(self.params, batch,
-                                          self.slot_capacity)
+            logits, cache, counts = _prefilled(self._prefill(
+                self.params, batch, self.slot_capacity))
         frontend = self.model.cfg.frontend_tokens or 0
         need = prompt.shape[1] + req.max_new_tokens + frontend
         n_blocks = (self.layout.blocks_for(need)
@@ -213,11 +225,14 @@ class RequestScheduler:
         with span("serve.pool_admit", rid=req.rid):
             table = self.pool.admit(paged, need)  # may raise PoolExhausted
         self._tables[slot] = table
+        slot_state, total = _split_counts(self._resident)
         self._resident = jax.tree_util.tree_map(
             lambda ax, st, leaf: (st if ax is not None else
                                   st.at[slot].set(leaf.astype(st.dtype))),
-            self.seq_axes, self._resident, cache,
+            self.seq_axes, slot_state, cache,
             is_leaf=lambda x: x is None)
+        if total is not None:
+            self._resident["counts"] = total + counts
         first = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)  # [1,1]
         self._tokens = self._tokens.at[slot].set(first)
         self._active[slot] = True
@@ -334,7 +349,26 @@ class RequestScheduler:
                         self._retire(slot, logits_np[slot], step_idx,
                                      results)
             step_idx += 1
+        if self.device_counters:
+            self._resident, total = _split_counts(self._resident)
+            for name, n in zip(self.device_counters, np.asarray(total)):
+                self.device_counters[name] += int(n)
+            self._resident["counts"] = jnp.zeros_like(total)
         return results
+
+
+def _prefilled(out) -> Tuple[jnp.ndarray, PyTree, Any]:
+    """A prefill's (logits, cache, counts): the family's counters where its
+    prefill returns them third, else None."""
+    logits, cache, *counts = out
+    return logits, cache, (counts[0] if counts else None)
+
+
+def _split_counts(resident: PyTree) -> Tuple[PyTree, Any]:
+    """The slot-resident state without the stream's counter sums, and the
+    sums (None where the scheduler counts nothing)."""
+    resident = dict(resident)
+    return resident, resident.pop("counts", None)
 
 
 def _takes_paged_step(model, mesh) -> bool:
@@ -369,11 +403,14 @@ def build_step(model, pool: KVBlockPool, seq_axes: PyTree, mesh=None,
         def write(leaf, layer, new):
             return pool.write_token(leaf, layer, page, off, new)
 
+        resident, total = _split_counts(resident)
         with jax.named_scope("serve.decode"):
-            logits, cache = model.decode_paged(
+            logits, cache, counts = model.decode_paged(
                 params, tokens[:, 0], merge_cache(buffers, resident, seq_axes),
-                tables, write)
+                tables, write, active)
         buffers, resident = split_cache(cache, seq_axes)
+        if total is not None:
+            resident["counts"] = total + counts
         return logits[:, -1], sample(logits), resident, buffers
 
     def chunks_at(paged: PyTree, positions: jnp.ndarray) -> PyTree:

@@ -57,12 +57,12 @@ FAMILY_ARCHS = {
     "encdec": "seamless-m4t-medium",
 }
 
-# backends per family: the dense transformer sweeps all three; the heavier
-# families get the oracle + the compiled kernel (chunked-lse shares the
-# vmap-level bitwise proof with dense-ref).
+# backends per family: the paged families (transformer, moe) sweep all
+# three; the others get the oracle + the compiled kernel (chunked-lse
+# shares the vmap-level bitwise proof with dense-ref).
 BACKENDS = {
     "transformer": ("dense-ref", "chunked-lse", "pallas-splitk"),
-    "moe": ("dense-ref", "pallas-splitk"),
+    "moe": ("dense-ref", "chunked-lse", "pallas-splitk"),
     "hybrid": ("dense-ref", "pallas-splitk"),
     "encdec": ("dense-ref", "chunked-lse", "pallas-splitk"),
 }
@@ -77,13 +77,9 @@ def _backend(name):
 
 
 def _family_cfg(family):
-    cfg = get_config(FAMILY_ARCHS[family]).reduced()
-    if family == "moe":
-        # disable capacity drops + pick a routing-tie-free init (same
-        # reasoning as tests/test_sharded_decode.py)
-        cfg = dataclasses.replace(cfg,
-                                  moe_capacity_factor=float(cfg.n_experts))
-    return cfg
+    # moe serves dropless; its tests pick a routing-tie-free init (same
+    # reasoning as tests/test_sharded_decode.py)
+    return get_config(FAMILY_ARCHS[family]).reduced()
 
 
 def _mk_requests(cfg, rng, n, arrivals):
@@ -239,10 +235,30 @@ class TestPagedStep:
             np.testing.assert_array_equal(r.tokens, paged[r.rid].tokens)
             assert np.array_equal(r.final_logits, paged[r.rid].final_logits)
 
-    @pytest.mark.parametrize("family", ["moe", "encdec"])
+    @pytest.mark.parametrize("backend", BACKENDS["moe"])
+    def test_moe_decodes_every_step_paged(self, backend):
+        """The expert layer rides the same paged step: every step reads
+        the pool through the block tables, and the counters count the
+        expert rows of every prefill and of each step's occupied slots."""
+        cfg = _family_cfg("moe")
+        eng = ServingEngine(cfg, seed=1, attn_backend=_backend(backend))
+        reqs = _mk_requests(cfg, np.random.default_rng(7), 4,
+                            arrivals=[0, 0, 1, 2])
+        sched = _scheduler(eng, reqs)
+        sched.run(reqs)
+        assert sched.paged and sched.steps_run > 0
+        assert sched.paged_steps == sched.steps_run
+        n_moe = cfg.n_layers - cfg.first_dense_layers
+        prompt = sum(len(r.prompt) for r in reqs)
+        assert sched.device_counters["moe_rows"] == (
+            prompt + sched.tokens_emitted) * n_moe * cfg.experts_per_token
+        if backend == "pallas-splitk":
+            assert sched.read_positions < sched.capacity_positions
+
+    @pytest.mark.parametrize("family", ["hybrid", "encdec"])
     def test_other_families_gather(self, family):
         cfg = _family_cfg(family)
-        eng = ServingEngine(cfg, seed=1 if family == "moe" else 0)
+        eng = ServingEngine(cfg)
         reqs = _mk_requests(cfg, np.random.default_rng(7), 2,
                             arrivals=[0, 0])
         sched = _scheduler(eng, reqs)

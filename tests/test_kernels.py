@@ -16,6 +16,9 @@ from repro.kernels.decode_attention.decode_attention import decode_attention
 from repro.kernels.decode_attention.ops import decode_mha, decode_mha_paged
 from repro.kernels.decode_attention.ref import decode_attention_ref
 from repro.kernels.flash_attention.ops import mha
+from repro.kernels.moe_gmm.moe_gmm import grouped_swiglu
+from repro.kernels.moe_gmm.ops import moe_gmm, row_tile, tile_layout
+from repro.kernels.moe_gmm.ref import moe_gmm_ref
 from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.kernels.ssd_scan.ops import ssd
 from repro.kernels.ssd_scan.ref import ssd_scan_ref
@@ -295,6 +298,123 @@ class TestPagedDecodeAttention:
             np.testing.assert_array_equal(np.asarray(got),
                                           np.asarray(new[s, 0]))
         _assert_paged_matches_gathered(q, kp2, vp2, tables, lengths, layer)
+
+
+def _gmm_case(rng, dtype, sizes, rows, d=128, f=256):
+    E = len(sizes)
+    x = jnp.asarray(rng.standard_normal((rows, d)), dtype)
+    w = lambda *s: jnp.asarray(rng.standard_normal(s) * s[-2] ** -0.5, dtype)
+    return (x, jnp.asarray(sizes, jnp.int32), w(E, d, f), w(E, d, f),
+            w(E, f, d))
+
+
+class TestMoeGmm:
+    """The grouped expert matmul in the interpreter against its oracle:
+    each row through its own expert's SwiGLU FFN, rows past the groups
+    zero."""
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("sizes,rows", [
+        ([3, 0, 17, 1, 9], 37),          # an empty group, sizes off the tile
+        ([0, 0, 24, 0], 24),             # every row in one group
+        ([16, 32, 0, 16], 64),           # sizes on the tile
+        ([1, 1, 1, 1, 1, 1, 1, 1], 12),  # one row each, rows to spare
+        ([0, 0, 0], 6),                  # no row at all
+    ])
+    def test_matches_ref(self, dtype, sizes, rows):
+        x, gs, wg, wu, wd = _gmm_case(np.random.default_rng(len(sizes)),
+                                      dtype, sizes, rows)
+        got = moe_gmm(x, gs, wg, wu, wd, interpret=True)
+        want = moe_gmm_ref(x, gs, wg, wu, wd)
+        assert got.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   **TOL[dtype])
+        np.testing.assert_array_equal(np.asarray(got)[sum(sizes):], 0.0)
+
+    def test_bf16_operands_accumulate_in_f32(self):
+        """bf16 in, f32 sums: the result equals the f32 computation on the
+        same bf16 values (the gated product rounded to bf16), not a bf16
+        accumulation."""
+        x, gs, wg, wu, wd = _gmm_case(np.random.default_rng(3), jnp.bfloat16,
+                                      [20, 12], 32, d=512)
+        got = np.asarray(moe_gmm(x, gs, wg, wu, wd, interpret=True))
+        up = lambda a: a.astype(jnp.float32)
+        e = np.repeat(np.arange(2), [20, 12])
+        g = np.einsum("md,mdf->mf", up(x), up(wg)[e])
+        u = np.einsum("md,mdf->mf", up(x), up(wu)[e])
+        h = np.asarray(jnp.asarray(g / (1 + np.exp(-g)) * u, jnp.bfloat16),
+                       np.float32)
+        want = np.einsum("mf,mfd->md", h, up(wd)[e])
+        # f32 sums differ only in their order; a bf16 result would not
+        # hold this tolerance
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+        as_bf16 = np.asarray(jnp.asarray(want, jnp.bfloat16), np.float32)
+        assert np.abs(as_bf16 - want).max() > 1e-3
+
+    def test_layer_of_a_stack(self):
+        """Given a layer, the weights are a stack of layers and the call
+        uses that layer's experts."""
+        x, gs, wg, wu, wd = _gmm_case(np.random.default_rng(6), jnp.float32,
+                                      [5, 0, 7], 12)
+        stack = lambda w: jnp.stack([w * 2.0, w, w * 3.0])
+        want = moe_gmm_ref(x, gs, wg, wu, wd)
+        for interpret in (True, None):
+            got = moe_gmm(x, gs, stack(wg), stack(wu), stack(wd), layer=1,
+                          interpret=interpret)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       **TOL[jnp.float32])
+
+    def test_rows_do_not_depend_on_other_rows(self):
+        """A row's result is the same bits whatever other rows share the
+        call: the expert layer's stream equals the solo oracle."""
+        x, gs, wg, wu, wd = _gmm_case(np.random.default_rng(4), jnp.bfloat16,
+                                      [5, 0, 7], 12)
+        full = np.asarray(moe_gmm(x, gs, wg, wu, wd, interpret=True))
+        alone = np.asarray(moe_gmm(x[5:6], jnp.asarray([0, 0, 1], jnp.int32),
+                                   wg, wu, wd, interpret=True))
+        np.testing.assert_array_equal(full[5], alone[0])
+
+    def test_layout_reads_each_used_expert_once(self):
+        """Every group is padded to whole tiles of one expert; the used
+        tiles of an expert are consecutive, so its weights are fetched
+        once, and an expert with no rows owns no tile."""
+        sizes = np.array([3, 0, 17, 1, 0, 9], np.int32)
+        rows = 40
+        tm = row_tile(rows, len(sizes))
+        n_tiles, src, dst, te, n_used = tile_layout(jnp.asarray(sizes), rows,
+                                                    tm)
+        te, n_used = np.asarray(te), int(n_used[0])
+        used = te[:n_used]
+        assert n_used == sum(-(-s // tm) for s in sizes)
+        assert list(dict.fromkeys(used)) == [0, 2, 3, 5]
+        assert (np.diff(used) >= 0).all() and (te[n_used:] == used[-1]).all()
+        # each sorted row lands once, in a tile of its own expert
+        dst = np.asarray(dst)[: sizes.sum()]
+        assert len(set(dst)) == len(dst)
+        e = np.repeat(np.arange(len(sizes)), sizes)
+        np.testing.assert_array_equal(te[dst // tm], e)
+        np.testing.assert_array_equal(np.asarray(src)[dst],
+                                      np.arange(sizes.sum()))
+
+    @pytest.mark.parametrize("rows,experts,tm", [
+        (192, 32, 16), (1536, 32, 64), (12288, 32, 128), (6, 8, 16)])
+    def test_row_tile(self, rows, experts, tm):
+        assert row_tile(rows, experts) == tm
+
+    def test_kernel_grid_matches_ref(self):
+        """The kernel itself over a hand-made layout and a stack of two
+        layers' experts, reading layer 1's in place: unused tiles give
+        zeros."""
+        x, gs, wg, wu, wd = _gmm_case(np.random.default_rng(5), jnp.float32,
+                                      [16, 16], 48)
+        stack = lambda w: jnp.stack([w * 0 + 7.0, w])
+        got = grouped_swiglu(x, jnp.asarray([0, 1, 1], jnp.int32),
+                             jnp.asarray([2], jnp.int32),
+                             jnp.asarray([1], jnp.int32), stack(wg),
+                             stack(wu), stack(wd), tm=16, interpret=True)
+        want = moe_gmm_ref(x, gs, wg, wu, wd)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   **TOL[jnp.float32])
 
 
 class TestSsdScan:

@@ -131,7 +131,7 @@ def _family_fixture(family):
         pre = lambda p, b: transformer.prefill(p, b["tokens"], cfg, CAP_REQ,
                                                layout=LAYOUT)
     elif family == "moe":
-        pre = lambda p, b: moe.prefill(p, b["tokens"], cfg, CAP_REQ, 1,
+        pre = lambda p, b: moe.prefill(p, b["tokens"], cfg, CAP_REQ,
                                        layout=LAYOUT)
     elif family == "hybrid":
         pre = lambda p, b: hybrid.prefill(p, b["tokens"], cfg, CAP_REQ,

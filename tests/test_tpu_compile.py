@@ -24,13 +24,12 @@ from repro.core.backends import SPLITK_BLOCK_K_TABLE  # noqa: E402
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """A SingleDeviceSharding on chip 0 of a described v5e:2x2, with the
-    persistent compilation cache off (a described-chip compile is written
-    to it but cannot be read back without a chip)."""
+def v5e():
+    """A described v5e:2x2 topology, with the persistent compilation cache
+    off (a described-chip compile is written to it but cannot be read
+    back without a chip)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
-    from jax.sharding import SingleDeviceSharding
 
     mp = pytest.MonkeyPatch()
     mp.setenv("TPU_LOG_DIR", "disabled")
@@ -42,10 +41,18 @@ def one_chip():
                                             topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was_on)
     cc.reset_cache()
     mp.undo()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    """A SingleDeviceSharding on chip 0 of the described v5e:2x2."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 def _compile(sharding, fn, *shapes):
@@ -203,3 +210,119 @@ def test_internlm2_paged_decode_step(one_chip):
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < gathered
     assert temp < buffers["k"].size * buffers["k"].dtype.itemsize
+
+
+def test_deepseek_moe_paged_decode_step(one_chip, monkeypatch):
+    """The scheduler's paged step for deepseek-moe-16b at ``dsmoe_conv``'s
+    shapes: the published widths, 7 layers (the dense one and six expert
+    layers), 32 of the 64 experts held, 32 slots at capacity 2560, with
+    compiled ``pallas-splitk`` and the grouped expert kernel.  Its scratch
+    stays below one gathered cache of every slot, so no whole-cache copy
+    hides in it, nor a copy of the expert weights."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.core.backends import PallasSplitKAttention
+    from repro.kernels.moe_gmm import ops as gmm_ops
+    from repro.models import moe
+    from repro.models.registry import get_model
+    from repro.serving.kv_pool import BlockAllocator, KVBlockPool
+    from repro.serving.scheduler import build_step
+
+    # the model's kernel path, which off a TPU takes the jnp oracle
+    monkeypatch.setattr(moe, "moe_gmm",
+                        partial(gmm_ops.moe_gmm, interpret=False))
+    cfg = dataclasses.replace(get_config("deepseek-moe-16b"), n_layers=7,
+                              held_experts=(0, 32))
+    attn = PallasSplitKAttention(interpret=False)
+    model = get_model(cfg, attn_backend=attn)
+    slots, cap = 32, 2560
+    layout = attn.cache_layout(cap)
+    width = cap // layout.block_k
+    num_blocks = 2 + slots * width
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    template = jax.eval_shape(
+        lambda p: model.prefill(p, {"tokens": jnp.zeros((1, 1), jnp.int32)},
+                                cap)[1], params)
+    axes = model.cache_seq_axes(template)
+    buffers = jax.eval_shape(
+        lambda t: KVBlockPool.build(t, axes, layout, num_blocks).buffers,
+        template)
+    pool = KVBlockPool(layout=layout, num_blocks=num_blocks,
+                       table_width=width, seq_axes=axes, buffers=None,
+                       allocator=BlockAllocator(num_blocks))
+    place = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    resident = {"stacks": [{"k": None, "v": None}, {"k": None, "v": None}],
+                "length": sds((slots,), jnp.int32),
+                "counts": sds((len(model.counters),), jnp.int32)}
+    compiled = build_step(model, pool, axes).lower(
+        place(params), sds((slots, 1, 1), jnp.int32), resident,
+        place(buffers), sds((slots, width), jnp.int32),
+        sds((slots,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "_decode_mha_jit_paged" in text and "_moe_gmm_jit" in text
+    gathered = (slots * 2 * cfg.n_layers * cfg.n_kv_heads * cap * cfg.d_head
+                * 2)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < gathered
+    # nor a copy of a layer's expert weights (a layer scan slicing them
+    # for the kernel, which cannot fuse the slice, made one per layer)
+    assert temp < 32 * cfg.d_model * cfg.moe_d_ff * 2
+
+
+# deepseek-moe-16b's experts: 6 expert layers, d 2048, f 1408, 32 held; a
+# decode step of 32 slots (192 assignments) and a prefill of 2048 tokens
+# (12288), one layer of the stack read in place.
+@pytest.mark.parametrize("rows", [192, 12288])
+def test_moe_gmm(one_chip, rows):
+    from repro.kernels.moe_gmm.ops import _grouped, row_tile
+
+    L, E, d, f, bf16 = 6, 32, 2048, 1408, jnp.bfloat16
+    compiled = _compile(
+        one_chip, partial(_grouped, tm=row_tile(rows, E), interpret=False),
+        ((rows, d), bf16), ((E,), jnp.int32), ((), jnp.int32),
+        ((L, E, d, f), bf16), ((L, E, d, f), bf16), ((L, E, f, d), bf16))
+    assert "_moe_gmm_jit" in compiled.as_text()
+    # no copy of a layer's expert weights: scratch below one layer's three
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * E * d * f * 2
+
+
+def test_moe_ep_train_grad(v5e, monkeypatch):
+    """Training's opt-in expert parallelism (``moe_ffn_shardmap``, the
+    dryrun's ``ep`` strategy) differentiates and compiles over the four
+    described chips, each holding a quarter of the experts.  The grouped
+    expert kernel, which has no transpose, is put in place of the model's
+    jnp path, so a training path that reached it would fail here."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.configs import get_config
+    from repro.kernels.moe_gmm import ops as gmm_ops
+    from repro.models import layers as L
+    from repro.models import moe
+
+    monkeypatch.setattr(moe, "moe_gmm",
+                        partial(gmm_ops.moe_gmm, interpret=False))
+    cfg = get_config("deepseek-moe-16b").reduced()
+    mesh = Mesh(np.array(v5e.devices).reshape(1, 4), ("data", "model"))
+    rep = NamedSharding(mesh, P())
+    place = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep), t)
+    params = jax.eval_shape(lambda: moe.init_moe_ffn(jax.random.key(0), cfg))
+    x = jax.ShapeDtypeStruct((4, 16, cfg.d_model), jnp.bfloat16, sharding=rep)
+
+    def loss(p, x):
+        out, aux = moe.moe_ffn_shardmap(p, x, cfg)
+        return out.astype(jnp.float32).sum() + aux["lb_loss"]
+
+    L.set_shard_ctx(mesh, ("data",), "model")
+    try:
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            place(params), x).compile()
+    finally:
+        L.set_shard_ctx()
+    text = compiled.as_text()
+    assert "all-reduce" in text and "tpu_custom_call" not in text

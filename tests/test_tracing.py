@@ -225,3 +225,17 @@ def test_decode_step_scopes(engine):
     for scope in ("serve.pool_gather", "serve.pool_scatter", "serve.decode",
                   "serve.sample"):
         assert scope in text, scope
+
+
+def test_moe_step_scopes():
+    """deepseek-moe's paged step: the expert layer's route, experts and
+    combine scopes beside the step's own, and no gather."""
+    engine = ServingEngine(get_config("deepseek-moe-16b").reduced(), seed=1)
+    sched = make_scheduler(engine)
+    args = (sched.params, sched._tokens, sched._resident, sched.pool.buffers,
+            sched._tables_dev, sched._active_dev)
+    text = sched._step_fn.lower(*args).as_text(debug_info=True)
+    for scope in ("moe.route", "moe.experts", "moe.combine", "serve.decode",
+                  "serve.pool_scatter"):
+        assert scope in text, scope
+    assert "serve.pool_gather" not in text
