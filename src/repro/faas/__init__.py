@@ -11,7 +11,8 @@ eager import here would make ``import repro.core.fsi`` circular.
 """
 
 _SIMULATOR_EXPORTS = ("LatencyModel", "run_fsi", "FsiRunResult",
-                      "FaultPlan", "FleetFailure")
+                      "FaultPlan", "FleetFailure", "clear_fleet_cache",
+                      "fleet_cache_info")
 
 __all__ = list(_SIMULATOR_EXPORTS)
 

@@ -7,7 +7,9 @@ panels to worker 0.  Every byte is really serialized/compressed/capped and
 billed; worker clocks advance per the latency model, so the result carries
 both the *output* (validated against the dense oracle in tests) and the
 *latency + $-cost* profile (validated against the paper's §VI numbers in
-benchmarks).
+benchmarks).  The offline part (partition, plans, worker artifacts, the
+fleet's device panels) is prepared once per net and reused by later calls
+(``faas/fleet_cache``).
 
 Fault tolerance: stragglers are modeled as slowed-down workers; when
 ``reinvoke_stragglers`` is set, workers whose per-layer compute exceeds
@@ -57,6 +59,14 @@ from repro.core.spans import span, spanned
 from repro.data.graphchallenge import GraphChallengeNet
 from repro.faas.chaos import ChaosState, FaultPlan, FleetFailure
 from repro.faas.collectives import reduce_to_root
+from repro.faas.fleet_cache import (
+    PreparedFleet,
+    clear_fleet_cache,
+    fleet_cache_info,
+    fleet_key,
+    lookup as lookup_fleet,
+    store as store_fleet,
+)
 from repro.faas.launch_tree import TreeSpec, launch_schedule, warm_pool_schedule
 from repro.faas.object_service import ObjectFabric
 from repro.faas.payload import Chunk
@@ -64,7 +74,8 @@ from repro.faas.queue_service import QueueFabric
 from repro.faas.worker import ComputeModel, EventLedger, WorkerState
 
 __all__ = ["LatencyModel", "SimulatorConfig", "FsiRunResult", "run_fsi",
-           "charge_weight_load", "FaultPlan", "FleetFailure"]
+           "charge_weight_load", "FaultPlan", "FleetFailure",
+           "clear_fleet_cache", "fleet_cache_info"]
 
 Channel = Literal["queue", "object", "serial", "auto"]
 
@@ -237,6 +248,15 @@ def run_fsi(
     diagnostics.  With ``faults=None`` nothing changes — every billable
     counter stays bit-identical to the fault-free baseline.  Fault
     injection drives the per-worker host path (no fleet batching).
+
+    The prepared fleet (partition, comm plans, worker artifacts, device
+    panels) is kept per net, ``P``, partition and backend and reused by
+    the next call (:mod:`repro.faas.fleet_cache`: the key, and the contract
+    that a net's layer arrays are not mutated in place once it has run).
+    A reused fleet gives the same output, bill and metrics as a cold one;
+    the weight load stays billed per worker per call.
+    ``fleet_cache_info()`` counts hits and misses, ``clear_fleet_cache()``
+    drops every entry.
     """
     latency = latency or LatencyModel()
     compute = compute or ComputeModel()
@@ -274,24 +294,39 @@ def run_fsi(
         )
 
     # ---------------- offline partitioning + plans --------------------------
+    # Prepared once per net (faas/fleet_cache): a later call with the same
+    # net, partition, P and backend finds the fleet and does only its
+    # per-request work.  The spans open either way; on a hit they cover the
+    # lookup.
+    key = fleet_key(net, P, partition, partition_method, seed, backend)
+    fleet = lookup_fleet(key)
     if partition is None:
         with span("fsi.partition"):
-            partition = partition_network(net.layers, P,
-                                          method=partition_method, seed=seed)
+            partition = (fleet.partition if fleet is not None else
+                         partition_network(net.layers, P,
+                                           method=partition_method, seed=seed))
     with span("fsi.plans"):
-        plans = build_comm_plans(net.layers, partition)
+        plans = (fleet.plans if fleet is not None
+                 else build_comm_plans(net.layers, partition))
     memory_mb = memory_mb or _default_memory_mb(net.neurons)
-    with span("fsi.prepare"):
-        artifacts = prepare_worker_artifacts(net.layers, partition, plans,
-                                             backend=backend)
-        # Fleet batching: pallas-bsr stacks each layer's per-worker operands
-        # so one device dispatch serves all P workers; pallas-bsr-sharded
-        # lays that stack over a `worker` mesh axis (shard_map, blocked P/D
-        # per device); numpy backends return None and finish per worker.
-        fleet_states = backend.fleet_prepare_all(
-            [[artifacts[m].layers[k].state_for(backend) for m in range(P)]
-             for k in range(net.n_layers)]
-        )
+    with span("fsi.prepare", cached=int(fleet is not None)):
+        if fleet is None:
+            artifacts = prepare_worker_artifacts(net.layers, partition, plans,
+                                                 backend=backend)
+            # Fleet batching: pallas-bsr stacks each layer's per-worker
+            # operands so one device dispatch serves all P workers;
+            # pallas-bsr-sharded lays that stack over a `worker` mesh axis
+            # (shard_map, blocked P/D per device); numpy backends return
+            # None and finish per worker.
+            fleet_states = backend.fleet_prepare_all(
+                [[artifacts[m].layers[k].state_for(backend) for m in range(P)]
+                 for k in range(net.n_layers)]
+            )
+            fleet = store_fleet(key, net, PreparedFleet(
+                partition=partition, plans=plans, artifacts=artifacts,
+                fleet_states=fleet_states,
+                imbalance=partition.imbalance(net.layers)))
+        artifacts, fleet_states = fleet.artifacts, fleet.fleet_states
         for a in artifacts:
             need = a.memory_bytes(batch)
             if need > memory_mb * 1024 * 1024:
@@ -569,7 +604,7 @@ def run_fsi(
 
     metrics = {
         "flops_total": float(sum(w.flops for w in workers)),
-        "imbalance": partition.imbalance(net.layers),
+        "imbalance": fleet.imbalance,
         # both clock models are always computed; the flag only selects which
         # one ``worker_times``/``stats`` report
         "phased_makespan_s": float(phased_times.max()),

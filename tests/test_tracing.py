@@ -19,7 +19,12 @@ jax = pytest.importorskip("jax")
 
 from repro.configs import get_config  # noqa: E402
 from repro.data.graphchallenge import make_inputs, make_sparse_dnn  # noqa: E402
-from repro.faas.simulator import FaultPlan, run_fsi  # noqa: E402
+from repro.faas.simulator import (  # noqa: E402
+    FaultPlan,
+    clear_fleet_cache,
+    fleet_cache_info,
+    run_fsi,
+)
 from repro.serving.engine import ServingEngine  # noqa: E402
 from repro.serving.scheduler import Request, RequestScheduler  # noqa: E402
 
@@ -70,17 +75,29 @@ def gc_case():
         make_inputs(128, 8, seed=1)
 
 
-@pytest.mark.parametrize("channel", ["queue", "object"])
-def test_fleet_call_spans(gc_case, channel, tmp_path):
+@pytest.mark.parametrize("channel,cached", [
+    ("queue", False), ("object", False), ("queue", True),
+], ids=["queue", "object", "queue-cached"])
+def test_fleet_call_spans(gc_case, channel, cached, tmp_path):
+    """A cold call, and one that finds the net's prepared fleet: the
+    preparation spans open once either way, ``fsi.prepare`` says which."""
     net, x0 = gc_case
     call = lambda: run_fsi(net, x0, P=4, channel=channel,  # noqa: E731
                            memory_mb=2000, partition_method="random")
+    clear_fleet_cache()
+    if cached:
+        call()
     res, threads = traced(call, tmp_path)
+    assert fleet_cache_info().hits == int(cached)
     spans = only_thread(threads)
     n = Counter(name for name, *_ in spans)
     assert n["fsi.call"] == 1
     for once in ("fsi.partition", "fsi.plans", "fsi.prepare"):
         assert n[once] == 1, once
+    for once in ("fsi.partition", "fsi.plans", "fsi.prepare"):
+        assert inside(spans, once, "fsi.call"), once
+    assert [st["cached"] for name, _, _, st in spans
+            if name == "fsi.prepare"] == [int(cached)]
     assert n["fsi.layer"] == N_LAYERS
     for per_layer in ("fsi.apply", "fsi.finish"):
         assert n[per_layer] == N_LAYERS, per_layer
